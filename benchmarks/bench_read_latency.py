@@ -45,16 +45,17 @@ def measured_local_gather_us(ecfg: EngramConfig, batch: int,
 
 def measured_miss_gather_us(ecfg: EngramConfig, n_miss: int,
                             table_rows: int = 65536) -> float:
-    """Wall time of a variable-count cache-miss gather through the padded
-    Pallas wrapper (the store's miss path)."""
+    """Host wall time of a variable-count cache-miss gather through the
+    store's miss path (XLA take on this host; the Pallas kernel is a TPU
+    path and its interpreter timing would mean nothing)."""
     small = EngramConfig(orders=ecfg.orders, n_heads=ecfg.n_heads,
                          emb_dim=ecfg.emb_dim, table_vocab=table_rows,
                          layers=ecfg.layers)
     rng = np.random.RandomState(0)
     tables = jnp.asarray(
-        rng.randn(small.n_tables, table_rows, small.head_dim)
+        rng.randn(small.n_tables, table_rows, small.table_lanes)
         .astype(np.float32))
-    fetch = TableFetcher(small, tables, impl="kernel")  # measure the kernel
+    fetch = TableFetcher(small, tables, impl="take")
     keys = rng.randint(0, small.n_tables * table_rows, size=n_miss)
     return timeit(lambda k: fetch(k), keys, warmup=2, iters=5) * 1e6
 
@@ -122,8 +123,8 @@ def run(fast: bool = False) -> None:
     if not fast:
         for n_miss in (7, 100, 1000):
             us = measured_miss_gather_us(e27, n_miss)
-            emit(f"read_latency/miss_gather_n{n_miss}", us,
-                 "padded Pallas miss-path gather")
+            emit(f"read_latency/miss_take_gather_n{n_miss}", us,
+                 "miss-path take gather (host wall time)")
 
 
 if __name__ == "__main__":

@@ -109,6 +109,15 @@ class EngramConfig:
         return self.emb_dim // self.n_heads
 
     @property
+    def table_lanes(self) -> int:
+        """Stored row width: ``head_dim`` padded to the TPU's 128-lane
+        tile. A tiled TPU layout pads the minor dim to 128 anyway, so on
+        the chip this costs no HBM, and it is what lets the Pallas gather
+        DMA rows out of the table in place. Lanes past ``head_dim`` are
+        never read."""
+        return -(-self.head_dim // 128) * 128
+
+    @property
     def n_tables(self) -> int:
         return len(self.orders) * self.n_heads
 

@@ -32,7 +32,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import EngramConfig, ModelConfig
-from ..sharding.rules import compat_shard_map, current_ctx, mesh_axes, shard
+from ..sharding.rules import current_ctx, mesh_axes, shard
 from ..models.params import pd
 from ..models.layers import rmsnorm
 from .hashing import engram_indices
@@ -55,7 +55,9 @@ def engram_defs(cfg: ModelConfig, dtype: str):
     v_pad = padded_vocab(e)
     fuse_dim = len(e.orders) * e.emb_dim
     per_layer = {
-        "tables": pd(e.n_tables, v_pad, e.head_dim,
+        # rows stored lane-padded (EngramConfig.table_lanes); every
+        # retrieval path reads the first head_dim lanes only
+        "tables": pd(e.n_tables, v_pad, e.table_lanes,
                      axes=(None, "eng_vocab", None), dtype=dtype),
         "proj": pd(fuse_dim, cfg.d_model, axes=("eng_emb", None), dtype=dtype),
         "gate": pd(cfg.d_model, cfg.d_model, axes=(None, None), dtype=dtype),
@@ -68,26 +70,31 @@ def engram_defs(cfg: ModelConfig, dtype: str):
 # retrieval strategies
 # ---------------------------------------------------------------------------
 
-def _take_rows(tables, idx):
-    """tables (T,V,hd); idx (B,S,T) -> (B,S,T,hd) via per-table gather."""
-    outs = [jnp.take(tables[t], idx[..., t], axis=0)
-            for t in range(tables.shape[0])]
-    return jnp.stack(outs, axis=-2)
+def _take_rows(tables, idx, hd: int):
+    """tables (T,V,lanes); idx (B,S,T) -> (B,S,T,hd): one gather over the
+    flattened tables, then each row's first ``hd`` lanes (slicing the
+    tables first would copy them)."""
+    T, V, lanes = tables.shape
+    gid = idx + jnp.arange(T, dtype=idx.dtype) * V
+    return jnp.take(tables.reshape(T * V, lanes), gid, axis=0)[..., :hd]
 
 
 def retrieve_local(ecfg: EngramConfig, tables, idx):
-    rows = _take_rows(tables, idx)
+    rows = _take_rows(tables, idx, ecfg.head_dim)
     B, S, T, hd = rows.shape
     return rows.reshape(B, S, T * hd)
 
 
-def retrieve_local_kernel(ecfg: EngramConfig, tables, idx):
-    """Local gather through the Pallas scalar-prefetch kernel
-    (kernels/engram_gather) — the on-device hot path on real TPU."""
+def retrieve_local_kernel(ecfg: EngramConfig, tables, idx, *,
+                          interpret: bool = False):
+    """Local gather through the Pallas DMA kernel (kernels/engram_gather).
+    The kernel compiles for a TPU only; off the chip a caller (a test)
+    asks for the Pallas interpreter with ``interpret=True``."""
     from ..kernels.engram_gather.ops import engram_gather
-    rows = engram_gather(tables, idx)
-    B, S, T, hd = rows.shape
-    return rows.reshape(B, S, T * hd)
+    rows = engram_gather(tables, idx, interpret=interpret)
+    B, S, T, _ = rows.shape
+    hd = ecfg.head_dim
+    return rows[..., :hd].reshape(B, S, T * hd)
 
 
 def retrieve_tp(ecfg: EngramConfig, tables, idx):
@@ -105,12 +112,12 @@ def retrieve_tp(ecfg: EngramConfig, tables, idx):
     T, hd = ecfg.n_tables, ecfg.head_dim
 
     def local_fn(tab, ix):
-        # tab (T, v_loc, hd); ix (B_loc, S, T)
+        # tab (T, v_loc, lanes); ix (B_loc, S, T)
         base = jax.lax.axis_index(ax) * v_loc
         rel = ix - base
         okm = (rel >= 0) & (rel < v_loc)
         rel = jnp.clip(rel, 0, v_loc - 1)
-        rows = _take_rows(tab, rel)
+        rows = _take_rows(tab, rel, hd)
         rows = rows * okm[..., None].astype(rows.dtype)
         B, S = ix.shape[:2]
         rows = rows.reshape(B, S, T * hd)
@@ -120,10 +127,10 @@ def retrieve_tp(ecfg: EngramConfig, tables, idx):
     # divisibility-aware batch spec (long_500k has B=1 < |data|)
     spec_i = ctx.spec_for(idx.shape, ("batch", None, None))
     b_entry = spec_i[0] if len(spec_i) > 0 else None
-    fn = compat_shard_map(local_fn, mesh=ctx.mesh,
-                          in_specs=(P(None, ax, None), spec_i),
-                          out_specs=P(b_entry, None, ax),
-                          check_vma=False)
+    fn = jax.shard_map(local_fn, mesh=ctx.mesh,
+                       in_specs=(P(None, ax, None), spec_i),
+                       out_specs=P(b_entry, None, ax),
+                       check_vma=False)
     return fn(tables, idx)
 
 
@@ -145,7 +152,7 @@ def retrieve_pooled(ecfg: EngramConfig, tables, idx, *, slack: float = 2.0):
     T, hd = ecfg.n_tables, ecfg.head_dim
 
     def local_fn(tab, ix):
-        # tab (T, v_loc, hd) — this device's pool shard (owner of rows
+        # tab (T, v_loc, lanes) — this device's pool shard (owner of rows
         # [o*v_loc, (o+1)*v_loc) where o = linear index over pool_axes).
         # ix (B_loc', S, T) — this device's share of requests.
         B, S = ix.shape[:2]
@@ -198,7 +205,7 @@ def retrieve_pooled(ecfg: EngramConfig, tables, idx, *, slack: float = 2.0):
         recv_tid = _a2a(send_tid, pool_axes)
         # owner-side gather (the pool read; maps to kernels/engram_gather)
         safe = jnp.clip(recv_req, 0, v_loc - 1)
-        rows = tab[recv_tid.reshape(-1), safe.reshape(-1)]        # (N*cap, hd)
+        rows = tab[recv_tid.reshape(-1), safe.reshape(-1), :hd]   # (N*cap, hd)
         rows = rows * (recv_req.reshape(-1) >= 0)[:, None].astype(rows.dtype)
         # reply -> requester; rid is the unique-group slot, so rows land
         # in the compact unique buffer, then fan out to every duplicate
@@ -214,10 +221,10 @@ def retrieve_pooled(ecfg: EngramConfig, tables, idx, *, slack: float = 2.0):
 
     # divisibility-aware batch spec (long_500k has B=1 < |data|)
     spec_i = ctx.spec_for(idx.shape, ("batch", None, None))
-    fn = compat_shard_map(local_fn, mesh=ctx.mesh,
-                          in_specs=(P(None, pool_axes, None), spec_i),
-                          out_specs=spec_i,
-                          check_vma=False)
+    fn = jax.shard_map(local_fn, mesh=ctx.mesh,
+                       in_specs=(P(None, pool_axes, None), spec_i),
+                       out_specs=spec_i,
+                       check_vma=False)
     return fn(tables, idx)
 
 
@@ -242,7 +249,7 @@ def retrieve_host(ecfg: EngramConfig, tables, idx):
     from jax.experimental import compute_on
 
     with compute_on.compute_on("device_host"):
-        rows = _take_rows(tables, idx)
+        rows = _take_rows(tables, idx, ecfg.head_dim)
     B, S, T, hd = rows.shape
     return rows.reshape(B, S, T * hd)
 

@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the engram_gather kernel.
 
-Handles lane padding (hd -> multiple of 128), row-count padding, multi-table
-flattening, and CPU fallback (interpret mode runs the kernel body in Python
-for correctness; real deployments lower it for TPU).
+Handle lane padding (hd -> multiple of 128) for unpadded test tables,
+row-count padding, and multi-table flattening. ``interpret`` is an explicit
+argument everywhere: the interpreter is a CPU correctness harness, and no
+wrapper falls back to it on its own.
 """
 from __future__ import annotations
 
@@ -10,13 +11,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from .engram_gather import gather_rows
+from .engram_gather import LANES, gather_rows
 from .ref import engram_gather_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_to(x: int, m: int) -> int:
@@ -25,81 +23,60 @@ def _pad_to(x: int, m: int) -> int:
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
 def engram_gather(tables: jax.Array, idx: jax.Array, *,
-                  interpret: bool | None = None,
-                  block_rows: int = 8) -> jax.Array:
+                  interpret: bool = False,
+                  block_rows: int = 128) -> jax.Array:
     """tables (T, V, hd); idx (..., T) int32 -> rows (..., T, hd).
 
     Flattens the T sub-tables into one (T*V, hd) row space so a single
     kernel launch covers every hash head (maximum in-flight concurrency,
-    mirroring the paper's single fused wide-grid launch).
+    mirroring the paper's single fused wide-grid launch). An unpadded
+    ``hd`` is lane-padded here, which copies the tables on every call:
+    served tables are stored lane-padded (``EngramConfig.table_lanes``).
     """
-    interp = (not _on_tpu()) if interpret is None else interpret
     T, V, hd = tables.shape
     batch_shape = idx.shape[:-1]
-    n = 1
-    for s in batch_shape:
-        n *= s
     flat = tables.reshape(T * V, hd)
-    # global row ids: table t row r -> t*V + r
-    gid = (idx + (jnp.arange(T, dtype=idx.dtype) * V)).reshape(-1)
-
-    hd_p = _pad_to(hd, 128)
+    hd_p = _pad_to(hd, LANES)
     if hd_p != hd:
         flat = jnp.pad(flat, ((0, 0), (0, hd_p - hd)))
+    # global row ids: table t row r -> t*V + r
+    gid = (idx + (jnp.arange(T, dtype=idx.dtype) * V)).reshape(-1)
     N = gid.shape[0]
-    N_p = _pad_to(max(N, block_rows), block_rows)
-    if N_p != N:
-        gid = jnp.pad(gid, (0, N_p - N))
-    rows = gather_rows(flat, gid.astype(jnp.int32), interpret=interp,
-                       block_rows=block_rows)
-    rows = rows[:N, :hd]
-    return rows.reshape(*batch_shape, T, hd)
+    gid = jnp.pad(gid, (0, _pad_to(N, block_rows) - N))
+    rows = gather_rows(flat, gid, interpret=interpret, block_rows=block_rows)
+    return rows[:N, :hd].reshape(*batch_shape, T, hd)
 
 
 def _next_pow2(n: int) -> int:
     return 1 << max(n - 1, 1).bit_length()
 
 
-def pad_table_lanes(table: jax.Array) -> jax.Array:
-    """Pad a (V, hd) table's lane dim to the 128 boundary. Do this once at
-    table-construction time (it copies the whole table), then feed the
-    result to ``gather_rows_padded`` per wave."""
-    hd = table.shape[1]
-    hd_p = _pad_to(hd, 128)
-    if hd_p != hd:
-        table = jnp.pad(table, ((0, 0), (0, hd_p - hd)))
-    return table
-
-
-def gather_rows_padded(table: jax.Array, gid, *,
-                       interpret: bool | None = None,
-                       block_rows: int = 8) -> jax.Array:
+def gather_rows_padded(tables: jax.Array, gid, *, width: int | None = None,
+                       interpret: bool = False,
+                       block_rows: int = 128) -> jax.Array:
     """Variable-count row gather through the Pallas kernel.
 
-    ``gather_rows`` requires the row count to divide ``block_rows`` and a
-    128-aligned lane dim; cache-miss gathers (pool/store.py) produce an
-    *arbitrary* number of rows per wave. This wrapper pads the index
-    vector to the next power-of-two bucket (bounding jit recompiles to
-    O(log N) shapes as the miss count wanders), pads the lane dim if the
-    caller didn't (prefer ``pad_table_lanes`` once up front — padding
-    here copies the whole table per call), runs the kernel, and slices
-    the real rows back out.
+    Cache-miss gathers (pool/store.py) produce an *arbitrary* number of
+    rows per wave. This wrapper pads the host index vector to the next
+    power-of-two bucket (bounding compiles to O(log N) shapes as the miss
+    count wanders), runs the kernel, and slices the real rows and the
+    first ``width`` lanes back out.
 
-    table (V, hd); gid (N,) int — N may be anything >= 0 -> (N, hd).
+    tables (..., R, hd) lane-aligned, flattened over the leading dims
+    inside the kernel's jit (free: no copy of the table); gid (N,) host
+    ints, N >= 0 -> (N, width or hd).
     """
-    gid = jnp.asarray(gid, jnp.int32)
-    N = int(gid.shape[0])
+    gid = np.asarray(gid, np.int32).reshape(-1)
+    N = gid.shape[0]
+    width = tables.shape[-1] if width is None else width
     if N == 0:
-        return jnp.zeros((0, table.shape[1]), table.dtype)
-    interp = (not _on_tpu()) if interpret is None else interpret
-    hd = table.shape[1]
-    table = pad_table_lanes(table)
+        return jnp.zeros((0, width), tables.dtype)
     n_p = _pad_to(_next_pow2(N), block_rows)
-    if n_p != N:
-        gid = jnp.pad(gid, (0, n_p - N))      # pad rows re-read row 0: cheap
-    rows = gather_rows(table, gid, interpret=interp, block_rows=block_rows)
-    return rows[:N, :hd]
+    gid = np.pad(gid, (0, n_p - N))           # pad rows re-read row 0: cheap
+    rows = gather_rows(tables, jnp.asarray(gid), interpret=interpret,
+                       block_rows=block_rows)
+    return rows[:N, :width]
 
 
 __all__ = ["engram_gather", "engram_gather_ref", "gather_rows",
-           "gather_rows_padded", "pad_table_lanes"]
+           "gather_rows_padded"]

@@ -3,6 +3,10 @@
     PYTHONPATH=src python -m repro.launch.serve --arch deepseek-7b --reduced \
         --requests 16 --max-new 16 --pool CXL
 
+``--arch deepseek-7b-1chip`` serves the published widths on one TPU v5e
+(configs/deepseek_7b.py). The compilation cache follows
+``launch/cache.py``.
+
 Compares pools with --compare (baseline / +Engram(DRAM) / +Engram(CXL)),
 the Table 2 experiment shape. `--replicas N` serves the same workload from
 a Router fleet sharing one hot-row cache (the Table 3 DP shape).
@@ -16,6 +20,7 @@ import sys
 from ..configs.base import SpecConfig, StoreConfig, get_config
 from ..models.transformer import RunFlags
 from ..serving import Workload, serve
+from .cache import enable_compile_cache
 from .train import reduced_config
 
 
@@ -43,10 +48,12 @@ def run_once(cfg, *, requests: int, max_new: int, pool, params=None,
              spec: SpecConfig = None, prompt_pool: int = 0,
              replicas: int = 1, policy: str = "round_robin",
              shared_cache: bool = True, qps: float = 0.0,
-             warm_rows: int = 0, aging_half_life_s: float = 0.0):
+             warm_rows: int = 0, aging_half_life_s: float = 0.0,
+             gather: str = "take"):
     """One workload drive through `serving.serve` (kept as the stable
     knob-level entry the benchmarks call). Returns (frontend, stats):
-    the frontend is an `EngramRuntime` (or a `Router` for replicas>1)."""
+    the frontend is an `EngramRuntime` (or a `Router` for replicas>1).
+    ``gather`` names the pool miss-path gather (``Engine(gather=)``)."""
     # deployment default: the §Perf-validated decode path (bf16 scores —
     # numerically equivalent per tests/test_perf_flags.py, ~7x less decode
     # cache traffic). The dry-run baselines keep RunFlags() defaults.
@@ -62,7 +69,7 @@ def run_once(cfg, *, requests: int, max_new: int, pool, params=None,
     res = serve(cfg, workload, pool=pool, replicas=replicas, policy=policy,
                 shared_cache=shared_cache, warmup=warmup, params=params,
                 flags=flags, max_batch=max_batch, max_len=max_len, seed=seed,
-                emulate_step_s=emulate_step_s, spec=spec)
+                emulate_step_s=emulate_step_s, spec=spec, gather=gather)
     return res.frontend, res.stats
 
 
@@ -158,6 +165,7 @@ def main(argv=None) -> int:
                  "--prompt-pool/--replicas — run those as single-pool "
                  "invocations")
 
+    enable_compile_cache()
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     spec = SpecConfig(proposer=args.spec_proposer,
                       max_draft=args.max_draft) if args.speculate else None
@@ -206,7 +214,8 @@ def main(argv=None) -> int:
                   f"hit_rate={s.hit_rate:.3f} "
                   f"(cache={s.cache_rows} rows @ {s.cache_tier}), "
                   f"stall/wave={s.stall_s_per_wave * 1e6:.1f} us, "
-                  f"hidden {s.hidden_waves}/{s.waves} waves")
+                  f"hidden {s.hidden_waves}/{s.waves} waves, "
+                  f"gather={eng.engine.gather}")
             if s.spec_waves:
                 print(f"spec-prefetch: window={s.spec_window_steps:.2f} "
                       f"decode steps (measured), "

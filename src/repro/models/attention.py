@@ -234,6 +234,8 @@ def decode_attention(cfg: ModelConfig, params, h, cache, positions, kind: str,
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):
     hkv, hd = cfg.n_kv_heads, cfg.head_dim
-    z = jnp.zeros((batch, max_len, hkv, hd), dtype)
-    return {"k": shard(z, "batch", "kv_seq", "kv_heads", None),
-            "v": shard(z, "batch", "kv_seq", "kv_heads", None)}
+    # k and v get buffers of their own: the serving engine donates the
+    # cache to each step, and one buffer cannot be donated twice
+    return {name: shard(jnp.zeros((batch, max_len, hkv, hd), dtype),
+                        "batch", "kv_seq", "kv_heads", None)
+            for name in ("k", "v")}

@@ -4,7 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..sharding.rules import compat_shard_map, shard
+from ..sharding.rules import shard
 from .params import pd
 
 
@@ -120,7 +120,7 @@ def embed_lookup_local(params, tokens):
 
     spec_t = ctx.spec_for(tokens.shape, ("batch",) + (None,) * (tokens.ndim - 1))
     b_entry = spec_t[0] if len(spec_t) > 0 else None
-    fn = compat_shard_map(local_fn, mesh=ctx.mesh,
+    fn = _jax.shard_map(local_fn, mesh=ctx.mesh,
                         in_specs=(P(ax, None), spec_t),
                         out_specs=P(b_entry, *([None] * tokens.ndim)),
                         check_vma=False)

@@ -26,8 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig, MoEConfig
-from ..sharding.rules import (compat_shard_map, current_ctx, mesh_axes,
-                              shard)
+from ..sharding.rules import current_ctx, mesh_axes, shard
 from .layers import mlp, mlp_defs
 from .params import pd
 
@@ -183,7 +182,7 @@ def moe_ep_gather(cfg: ModelConfig, params, x, *, token_chunk: int = 4096):
 
     # divisibility-aware batch spec (decode/long shapes can have B < |data|)
     spec_x = ctx.spec_for(x.shape, ("batch", None, None))
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=ctx.mesh,
         in_specs=(P(ax, None, None), P(ax, None, None), P(None, None), spec_x),
         out_specs=(spec_x, P()),
@@ -264,7 +263,7 @@ def moe_ep_alltoall(cfg: ModelConfig, params, x):
     base = ctx.spec_for(x.shape, ("batch", None, None))
     b_entry = base[0] if len(base) > 0 else None
     spec_x = P(b_entry, ax, None)
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=ctx.mesh,
         in_specs=(P(ax, None, None), P(ax, None, None), P(None, None), spec_x),
         out_specs=(spec_x, P()),

@@ -9,6 +9,7 @@ logical axes, init scale). From one def-tree we derive:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import zlib
 from typing import Any, Optional
 
@@ -59,7 +60,10 @@ def _path_str(path) -> str:
     return "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def _init_leaf(d: ParamDef, key) -> jax.Array:
+    """One fused program per leaf: eager ops would hold the f32 draw and
+    its scaled copy at once, twice the leaf in f32 on the device."""
     if d.init == "zeros":
         return jnp.zeros(d.shape, d.dtype)
     if d.init == "ones":

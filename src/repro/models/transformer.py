@@ -267,18 +267,37 @@ def apply_segment(cfg: ModelConfig, flags: RunFlags, seg: Segment, params, h,
         if flags.remat and mode == "train":
             body = jax.checkpoint(period_body)
 
+        def decode_body(carry, xs):
+            # the stacked cache rides in the carry and each layer's update
+            # is written back in place: as scan xs -> ys it would need a
+            # second full-size cache buffer for the outputs
+            h_, aux_, c_all = carry
+            p_stacked, i = xs
+            c_all = list(c_all)
+            for pos in range(seg.period):
+                c = jax.tree.map(lambda x: x[i], c_all[pos])
+                h_, nc, aux = apply_block(cfg, flags, sigs[pos],
+                                          p_stacked[pos], h_, positions, c,
+                                          mode)
+                aux_ = aux_ + aux
+                c_all[pos] = jax.tree.map(
+                    lambda x, n: jax.lax.dynamic_update_index_in_dim(
+                        x, n.astype(x.dtype), i, 0), c_all[pos], nc)
+            return (h_, aux_, tuple(c_all)), None
+
         p_xs = tuple(params["stack"])
         c_xs = tuple(cache["stack"]) if cache is not None else None
-        if flags.scan_layers:
-            xs = (p_xs, c_xs)
-            if c_xs is None:
-                xs = (p_xs, None)
-                (h, aux_tot), ys = jax.lax.scan(
-                    lambda c, p: body(c, (p, None)), (h, aux_tot), p_xs)
-            else:
-                (h, aux_tot), ys = jax.lax.scan(body, (h, aux_tot),
-                                                (p_xs, c_xs))
-            new_stack = list(ys) if keep_cache and ys is not None else []
+        if flags.scan_layers and c_xs is not None:
+            # only decode passes caches
+            (h, aux_tot, c_new), _ = jax.lax.scan(
+                decode_body, (h, aux_tot, c_xs),
+                (p_xs, jnp.arange(seg.n_periods)))
+            new_stack = list(c_new)
+        elif flags.scan_layers:
+            # train and prefill: no cache in, prefill's caches out as ys
+            (h, aux_tot), ys = jax.lax.scan(
+                lambda c, p: body(c, (p, None)), (h, aux_tot), p_xs)
+            new_stack = list(ys) if keep_cache else []
         else:
             ys = []
             for r in range(seg.n_periods):

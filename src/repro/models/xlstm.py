@@ -205,6 +205,7 @@ def init_xlstm_cache(cfg: ModelConfig, kind: str, batch: int, dtype):
             "m": jnp.zeros((batch, H), jnp.float32),
         }
     dh = d // H
-    z = jnp.zeros((batch, H, dh), jnp.float32)
-    return {"conv": jnp.zeros((batch, K - 1, d), dtype),
-            "c": z, "n": z, "h": z, "m": jnp.zeros((batch, H), jnp.float32)}
+    # a buffer per leaf: the serving engine donates its decode state
+    cell = {k: jnp.zeros((batch, H, dh), jnp.float32) for k in ("c", "n", "h")}
+    return {"conv": jnp.zeros((batch, K - 1, d), dtype), **cell,
+            "m": jnp.zeros((batch, H), jnp.float32)}

@@ -516,36 +516,36 @@ class CachedStore(_StoreBase):
 
 class TableFetcher:
     """Materializes rows for flat packed segment keys from one layer's
-    Engram tables ``(T, V, hd)``.
+    lane-padded Engram tables ``(T, V, table_lanes)``. The fetcher holds
+    the params leaf itself: no second copy of the table on the device.
 
-    ``impl`` selects the gather:
-      * ``"kernel"`` — the variable-count Pallas gather
-        (``kernels/engram_gather.gather_rows_padded``): a cache-miss wave
-        of arbitrary segment count still takes the kernel hot path.
-      * ``"take"``   — a jitted ``jnp.take``: on non-TPU backends the
-        Pallas kernel runs in *interpret* mode, whose grid steps execute
-        one row at a time in Python — a correctness harness, not a data
-        path — so serving on those backends takes the XLA gather instead.
-      * ``"auto"``   — kernel on TPU, take elsewhere (the default).
+    ``impl`` selects the gather, and the caller always names it:
+      * ``"take"``   — a jitted XLA ``jnp.take`` (runs on every backend).
+      * ``"kernel"`` — the variable-count Pallas DMA gather
+        (``kernels/engram_gather.gather_rows_padded``): compiled for the
+        TPU; ``interpret=True`` runs its body in the Pallas interpreter,
+        a correctness harness for CPU tests and never a data path.
     """
 
-    def __init__(self, ecfg: EngramConfig, tables, impl: str = "auto"):
-        # hoist the kernel imports out of the per-wave call
-        from ..kernels.engram_gather.ops import (_on_tpu, gather_rows_padded,
-                                                 pad_table_lanes)
-        assert impl in ("auto", "kernel", "take"), impl
+    def __init__(self, ecfg: EngramConfig, tables, impl: str = "take",
+                 interpret: bool = False):
+        import jax
+        import jax.numpy as jnp
+        from ..kernels.engram_gather.ops import gather_rows_padded
+        assert impl in ("kernel", "take"), impl
         self.ecfg = ecfg
-        self.T, self.V, self.hd = tables.shape
-        self.impl = impl if impl != "auto" else \
-            ("kernel" if _on_tpu() else "take")
-        self._gather = gather_rows_padded
-        if self.impl == "take":
-            import jax
-            import jax.numpy as jnp
-            self._take = jax.jit(lambda t, g: jnp.take(t, g, axis=0))
-        # pad lanes to the 128 boundary ONCE — per-call padding would copy
-        # the full (T*V, hd) table on every cache-miss wave
-        self.flat = pad_table_lanes(tables.reshape(self.T * self.V, self.hd))
+        self.T, self.V, lanes = tables.shape
+        assert lanes == ecfg.table_lanes, (tables.shape, ecfg.table_lanes)
+        self.hd = ecfg.head_dim
+        self.impl = impl
+        self.tables = tables
+        hd = self.hd
+        if impl == "take":
+            self._gather = jax.jit(lambda t, g: jnp.take(
+                t.reshape(-1, t.shape[-1]), g, axis=0)[:, :hd])
+        else:
+            self._gather = lambda t, g: gather_rows_padded(
+                t, g, width=hd, interpret=interpret)
 
     def gid_for(self, keys) -> np.ndarray:
         """Flat row ids in this fetcher's (padded) table space for packed
@@ -558,9 +558,7 @@ class TableFetcher:
         in-layer row ids — passing them skips the redundant decomposition)."""
         if gid is None:
             gid = self.gid_for(keys)
-        if self.impl == "take":
-            return self._take(self.flat, np.asarray(gid))[:, :self.hd]
-        return self._gather(self.flat, gid)[:, :self.hd]
+        return self._gather(self.tables, np.asarray(gid, np.int32))
 
 
 # ---------------------------------------------------------------------------

@@ -56,8 +56,9 @@ ONE device->host array through ``_host()``:
 ``stats.d2h_pulls`` counts these syncs; ``_host`` wraps them in
 ``jax.transfer_guard_device_to_host("allow")`` so callers can pin the
 whole wave under a ``"disallow"`` guard (benchmarks/bench_hotpath.py,
-tests/test_hotpath.py). On the CPU backend the guard is inert (host and
-device share memory), so the counter is the enforced budget there.
+tests/test_hotpath.py, chip_smoke.py). On the CPU backend the guard is
+inert (host and device share memory), so the counter is the enforced
+budget there; on a TPU the guard raises on any other pull.
 
 Pool-tier emulation: on real hardware the Engram fetch either hides inside
 the prefetch window or stalls the step (paper §3.2). The engine delegates
@@ -66,8 +67,9 @@ that entirely to the tiered ``EngramStore`` subsystem (pool/store.py): a
 which owns tier latency, the optional hot-row cache, and measured hit-rate
 accounting — and the engine sleeps (real point) or accounts (emulated
 point) only the overshoot the scheduler reports. On pool runs the decode
-rows are materialized through ``TableFetcher`` — the padded Pallas
-miss-path gather — so cache-miss materialization is on-device end-to-end.
+rows are materialized through ``TableFetcher`` (an XLA take or the Pallas
+DMA gather, as ``gather=`` names), so cache-miss materialization is
+on-device end-to-end.
 `pool=None` (weights local/HBM) resolves to a ``LocalStore`` with zero
 emulated cost: that is the baseline, and the '+Engram (DRAM-local)'
 configs of Table 2 differ only by engram compute. ``engine.store.stats()``
@@ -331,7 +333,8 @@ class Engine:
                  slo_policy: Optional[OverloadPolicy] = None,
                  kv_pool: Optional[KVPagePool] = None,
                  arbiter: Optional[PoolArbiter] = None,
-                 idle_spill_tokens: Optional[int] = None):
+                 idle_spill_tokens: Optional[int] = None,
+                 gather: str = "take", device=None):
         """``emulate_step_s``: evaluate the pool stalls at a production
         operating point (ms-scale decode steps) instead of this host's
         CPU step times — stalls are then accounted in ``emu_time_s``
@@ -386,7 +389,16 @@ class Engine:
         link, and the request restored-and-resumed later bit-identically.
         ``arbiter``: a ``PoolArbiter`` metering that KV traffic against
         Engram rows on the shared link + hot-row cache. ``None`` (default)
-        keeps every legacy admission path bit-exact."""
+        keeps every legacy admission path bit-exact.
+
+        ``gather``: how pool runs materialize a wave's Engram rows
+        (``pool.store.TableFetcher``): ``"take"`` (XLA gather, any
+        backend) or ``"kernel"`` (the Pallas DMA gather, TPU only). The
+        engine never picks one by backend; ``self.gather`` says which ran.
+
+        ``device``: a ``jax.Device`` to hold this engine's params and
+        decode state (the router puts replica i on device i); None leaves
+        placement to JAX's default device."""
         assert not cfg.is_encoder, "serving needs a decoder"
         self.cfg = cfg
         self.name = name
@@ -400,7 +412,10 @@ class Engine:
         self.emulate_step_s = emulate_step_s
         self.clock = clock if clock is not None else VirtualClock()
         self.cursor = self.clock.cursor(name if name else "engine")
+        self.device = device
         self.params = params if params is not None else init_params(cfg, seed)
+        if device is not None:
+            self.params = jax.device_put(self.params, device)
         self.has_engram = bool(cfg.engram_layers()) and "engram" in self.params
         self._n_eng = len(cfg.engram_layers())
 
@@ -440,13 +455,15 @@ class Engine:
                                                layers=cfg.engram_layers(),
                                                n_layers=cfg.n_layers)
             if self.pool is not None:
-                # decode miss-path materialization through the padded
-                # Pallas gather: the store's pool read is a real on-device
-                # kernel launch, not a jnp.take detour
+                # decode miss-path materialization: the store's pool read
+                # gathers the wave's rows on the device, straight from the
+                # params' tables
                 self._fetchers = [
                     TableFetcher(cfg.engram,
-                                 self.params["engram"]["layers"][j]["tables"])
+                                 self.params["engram"]["layers"][j]["tables"],
+                                 impl=gather)
                     for j in range(self._n_eng)]
+        self.gather = gather
 
         self._pool_mode = self.pool is not None and self.has_engram
         # jitted fused index+key fns: keys are packed on-device (one int64
@@ -458,9 +475,14 @@ class Engine:
             if self._pool_mode else None)
         self._wave_sync = (jax.jit(self._wave_sync_fn)
                            if self._pool_mode else None)
-        self._prefill_fn = build_prefill_step(cfg, flags, max_len=max_len)
+        # unpadded prefill caches: update_slots writes them at [0, S) of
+        # the slot, so no max_len-padded copy of the group's KV exists
+        self._prefill_fn = build_prefill_step(cfg, flags)
         self._prefill = jax.jit(self._prefill_fn)
-        self._admit_wave = jax.jit(self._admit_wave_fn)
+        # every step that returns the engine's next decode state donates
+        # the current one: the KV cache is updated in place instead of a
+        # second max_batch x max_len copy living through each wave
+        self._admit_wave = jax.jit(self._admit_wave_fn, donate_argnums=(1, 2))
         # chunked-prefill admission (None = legacy monolithic groups)
         self.prefill_chunk = int(prefill_chunk) if prefill_chunk else None
         self.prefix_cache = prefix_cache
@@ -475,17 +497,18 @@ class Engine:
                 (self.prefix_cache.block_tokens, self.prefill_chunk)
         if self.prefill_chunk is not None:
             self._chunk_core = build_chunk_prefill(cfg, flags)
-            self._chunk_wave_jit = jax.jit(self._chunk_wave_fn)
+            self._chunk_wave_jit = jax.jit(self._chunk_wave_fn,
+                                           donate_argnums=(1, 2))
             # fresh-slot template: zeroed batch-1 state scattered over a
             # freed slot before its first chunk (positions/last_tokens of
             # the previous occupant must not leak into the new prompt)
-            self._state1 = init_decode_state(cfg, flags, 1, max_len)
+            self._state1 = self._fresh_state(1)
         self._decode_fn = build_decode_step(cfg, flags)
-        self._decode = jax.jit(self._decode_fn)
+        self._decode = jax.jit(self._decode_fn, donate_argnums=(1,))
         self._decode_ext_fn = build_decode_step(cfg, flags,
                                                 external_rows=True) \
             if self.has_engram else None
-        self._decode_ext = jax.jit(self._decode_ext_fn) \
+        self._decode_ext = jax.jit(self._decode_ext_fn, donate_argnums=(1,)) \
             if self._decode_ext_fn else None
         # chunked mode: while prefill jobs are in flight, decode waves run
         # GATED (serving/slots.gate_state) — a mid-prefill slot's
@@ -498,11 +521,13 @@ class Engine:
             assert self.spec is None, \
                 "chunked prefill does not compose with speculative " \
                 "decoding (the verify pass is ungated)"
-            self._decode_gated = jax.jit(self._decode_gated_fn)
+            self._decode_gated = jax.jit(self._decode_gated_fn,
+                                         donate_argnums=(1,))
             if self._decode_ext_fn is not None:
-                self._decode_ext_gated = jax.jit(self._decode_ext_gated_fn)
+                self._decode_ext_gated = jax.jit(self._decode_ext_gated_fn,
+                                                 donate_argnums=(1,))
         self._prefetch = jax.jit(self._prefetch_fn) if self.has_engram else None
-        self._insert = jax.jit(update_slots, static_argnames=())
+        self._insert = jax.jit(update_slots, donate_argnums=(0,))
 
         # speculate mode: verifier + proposer + block-shaped retrieval
         self.proposer = None
@@ -516,19 +541,21 @@ class Engine:
             self.proposer = proposer if proposer is not None \
                 else make_proposer(cfg, self.spec, flags=flags, seed=seed)
             self._verify = jax.jit(
-                self._fuse_verdict(build_verifier(cfg, flags)))
+                self._fuse_verdict(build_verifier(cfg, flags)),
+                donate_argnums=(1,))
             if self.has_engram:
                 self._verify_ext = jax.jit(self._fuse_verdict(
-                    build_verifier(cfg, flags, external_rows=True)))
+                    build_verifier(cfg, flags, external_rows=True)),
+                    donate_argnums=(1,))
                 if self._pool_mode:
                     self._block_keys = jax.jit(
                         lambda last, block: block_engram_keys(
                             cfg.engram, last, block, self._n_eng))
                 self._block_prefetch = jax.jit(self._block_prefetch_fn)
 
-        self.state = init_decode_state(cfg, flags, max_batch, max_len)
+        self.state = self._fresh_state(max_batch)
         self.slots: list[Optional[Request]] = [None] * max_batch
-        self.tokens = jnp.zeros((max_batch,), jnp.int32)
+        self.tokens = self._place(jnp.zeros((max_batch,), jnp.int32))
         self.queue: deque[Request] = deque()
         self.done: dict[int, Request] = {}
         self.cancelled: dict[int, Request] = {}
@@ -578,6 +605,21 @@ class Engine:
                 self.kv_pool = KVPagePool(1 << 30, 8)
         # rid -> _SpilledReq: preempted requests parked in the KV pool
         self._spilled: dict[int, _SpilledReq] = {}
+
+    def _place(self, tree):
+        """Commit ``tree`` to this engine's device (no-op without one)."""
+        if self.device is None:
+            return tree
+        return jax.device_put(tree, self.device)
+
+    def _fresh_state(self, batch: int):
+        """Zeroed decode state for ``batch`` slots, made by one program on
+        this engine's device. Built eagerly, each layer stack would be
+        concatenated from per-layer zeros: the KV cache twice over."""
+        out = None if self.device is None else \
+            jax.sharding.SingleDeviceSharding(self.device)
+        return jax.jit(lambda: init_decode_state(
+            self.cfg, self.flags, batch, self.max_len), out_shardings=out)()
 
     # ------------------------------------------------------------ public API
 
@@ -1123,7 +1165,7 @@ class Engine:
 
     def _miss_fetches(self, keys: np.ndarray):
         """Per-layer fetch closures materializing a wave's rows through
-        the padded Pallas miss-path gather (``TableFetcher``). ``keys``
+        the miss-path gather (``TableFetcher``). ``keys``
         is the FULL batch's (B, S, L, T) packed-key block — decode consumes
         rows for every slot, while the store is charged with live keys
         only. Row ids are derived from the packed keys exactly once per

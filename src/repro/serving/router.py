@@ -6,8 +6,9 @@ A private per-replica cache re-fetches every hot row once per replica;
 one shared cache lets replica B hit rows replica A already pulled from
 the backing tier. The router builds exactly that:
 
-  * N `Engine` replicas (shared params, private decode state/slots), each
-    wrapped in its `EngramRuntime`;
+  * N `Engine` replicas (one params tree, private decode state/slots),
+    each wrapped in its `EngramRuntime` and placed on its own device when
+    the host has several;
   * one `SharedCache` (pool/cache.py) mounted as every replica's
     `CachedStore` front-end (pool/store.py `make_store(cache=...)`), with
     per-replica and aggregate `stats()`;
@@ -28,6 +29,7 @@ import zlib
 from collections import deque
 from typing import Optional
 
+import jax
 import numpy as np
 
 from ..core.hashing import engram_indices
@@ -215,7 +217,11 @@ class Router:
         into every replica for priority dispatch + preemption, with ONE
         fleet-shared ``KVPagePool`` (preempted KV parks in the pooled
         tier, which is shared infrastructure, not per-replica DRAM).
-        ``arbiter``: the KV-vs-Engram ``PoolArbiter``, also fleet-wide."""
+        ``arbiter``: the KV-vs-Engram ``PoolArbiter``, also fleet-wide.
+
+        Replica r holds its params copy and decode state on
+        ``jax.devices()[r % n_devices]``: one replica per chip on a
+        multi-chip host, every replica on the one device otherwise."""
         assert replicas >= 1, replicas
         assert policy in POLICIES, (policy, POLICIES)
         self.cfg = cfg
@@ -275,6 +281,7 @@ class Router:
                 self.prefix_cache = PrefixKVCache(prefix_cache_bytes, chunk)
         if params is None:
             params = init_params(cfg, seed)
+        devices = jax.devices()
         self.replicas: list[EngramRuntime] = []
         for r in range(replicas):
             name = f"replica{r}"
@@ -298,7 +305,7 @@ class Router:
                          clock=self.clock, prefix_cache=pfx,
                          fabric=self.fabric, slo_policy=slo_policy,
                          kv_pool=self.kv_pool, arbiter=arbiter,
-                         **engine_kwargs)
+                         device=devices[r % len(devices)], **engine_kwargs)
             self.replicas.append(eng.runtime())
         self._rr = 0
 

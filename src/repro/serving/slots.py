@@ -75,15 +75,23 @@ def batch_axis(path, leaf) -> int:
 
 
 def update_slots(state, new_state, slots: jax.Array):
-    """Write new_state (batch k) into ``state`` (batch B) at ``slots`` (k,)."""
+    """Write new_state (batch k) into ``state`` (batch B) at ``slots`` (k,).
+
+    KV leaves of ``new_state`` may be shorter than the decode capacity (an
+    unpadded prefill): they land at positions ``[0, S)`` and the slot's
+    older entries past ``S`` stay, masked by ``positions`` until decoding
+    overwrites them. Indexing the batch axis in place (no transpose)
+    keeps the scatter from copying the whole cache."""
 
     def one(path, leaf, new_leaf):
         if leaf is None:
             return None
-        ax = batch_axis(path, leaf)
-        moved = jnp.moveaxis(leaf, ax, 0)
-        newm = jnp.moveaxis(new_leaf, ax, 0)
-        return jnp.moveaxis(moved.at[slots].set(newm.astype(moved.dtype)), 0, ax)
+        at = [slice(None)] * leaf.ndim
+        at[batch_axis(path, leaf)] = slots
+        sq = _seq_axis(path, leaf)
+        if sq is not None:
+            at[sq] = slice(0, new_leaf.shape[sq])
+        return leaf.at[tuple(at)].set(new_leaf.astype(leaf.dtype))
 
     return jax.tree_util.tree_map_with_path(one, state, new_state)
 

@@ -75,52 +75,6 @@ class ShardCtx:
         return NamedSharding(self.mesh, self.spec_for(shape, logical_axes), **kw)
 
 
-# ---------------------------------------------------------------------------
-# JAX version compatibility
-# ---------------------------------------------------------------------------
-# The repo targets the current JAX API (jax.shard_map with check_vma,
-# jax.make_mesh with axis_types); older installs (<=0.4.x) only have
-# jax.experimental.shard_map.shard_map(check_rep=...) and a make_mesh
-# without axis_types. These shims resolve the right spelling once.
-
-def compat_make_mesh(shape: tuple[int, ...],
-                     axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    """jax.make_mesh across JAX versions (axis_types is newer API)."""
-    if hasattr(jax.sharding, "AxisType"):
-        try:
-            return jax.make_mesh(
-                shape, axes,
-                axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-        except TypeError:
-            pass
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    n = int(np.prod(shape, initial=1))
-    devs = np.asarray(jax.devices()[:n]).reshape(shape)
-    return jax.sharding.Mesh(devs, axes)
-
-
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """jax.shard_map across JAX versions (check_vma was check_rep)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
-def compat_axis_size(axis) -> int:
-    """jax.lax.axis_size across versions (older JAX: psum of a static 1)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
-
-
 _TLS = threading.local()
 
 

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..sharding.rules import compat_axis_size
-
 
 def quantize(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Symmetric per-tensor int8. Returns (q int8, scale f32)."""
@@ -39,7 +37,7 @@ def dequantize(q: jax.Array, scale: jax.Array, dtype=jnp.float32) -> jax.Array:
 
 def _compressed_allreduce_local(x: jax.Array, axis: str) -> jax.Array:
     """Inside shard_map: all-reduce ``x`` over ``axis`` with int8 wire."""
-    n = compat_axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return x
     shape, dt = x.shape, x.dtype
@@ -76,6 +74,6 @@ def compressed_psum_tree(tree, axis: str):
 
 def compressed_pmean_tree(tree, axis: str):
     def one(x):
-        n = compat_axis_size(axis)
+        n = jax.lax.axis_size(axis)
         return compressed_psum(x, axis) / n
     return jax.tree.map(one, tree)

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
-from ..sharding.rules import compat_shard_map
 from ..models.transformer import RunFlags
 from ..models.model import build_loss_fn
 from .compress import compressed_pmean_tree
@@ -53,7 +52,7 @@ def build_ddp_train_step(cfg: ModelConfig, flags: RunFlags, oc: AdamWConfig,
     def step(params, opt_state, batch):
         rep = jax.tree.map(lambda _: P(), params)
         rep_o = jax.tree.map(lambda _: P(), opt_state)
-        fn = compat_shard_map(
+        fn = jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(rep, rep_o, batch_spec(batch)),
             out_specs=(rep, rep_o,

@@ -8,7 +8,8 @@ import pytest
 
 from repro.configs.base import EngramConfig, ModelConfig
 from repro.core.engram import (engram_defs, engram_fuse, engram_lookup,
-                               padded_vocab, retrieve, retrieve_local)
+                               padded_vocab, retrieve, retrieve_local,
+                               retrieve_local_kernel)
 from repro.core.hashing import engram_indices
 from repro.models.params import tree_init
 
@@ -53,7 +54,7 @@ def test_retrieve_kernel_matches_local(eng_params):
     idx = engram_indices(ECFG, toks)
     tab = eng_params["layers"][0]["tables"]
     ref = np.asarray(retrieve_local(ECFG, tab, idx))
-    out = np.asarray(retrieve(ECFG, tab, idx, "local_kernel"))
+    out = np.asarray(retrieve_local_kernel(ECFG, tab, idx, interpret=True))
     np.testing.assert_allclose(out, ref, rtol=1e-6)
 
 

@@ -84,11 +84,12 @@ def test_keys_to_gid_padded_tables(cfg, params):
     by_gid = np.asarray(fetcher(gid=gid))
     # direct reference: table t, row r from the raw (T, V_pad, hd) tables
     t_ids = np.tile(np.arange(e.n_tables), idx.size // e.n_tables)
-    ref = np.asarray(tab)[t_ids, idx.reshape(-1)]
+    ref = np.asarray(tab)[t_ids, idx.reshape(-1), :e.head_dim]
     assert np.array_equal(by_keys, by_gid)
     assert np.allclose(by_keys, ref)
-    # the Pallas-kernel impl and the XLA-take impl agree bit-for-bit
-    kern = TableFetcher(e, tab, impl="kernel")
+    # the Pallas-kernel impl (interpreted off the TPU) and the XLA-take
+    # impl agree bit-for-bit
+    kern = TableFetcher(e, tab, impl="kernel", interpret=True)
     assert np.array_equal(np.asarray(kern(gid=gid)), by_gid)
 
 
