@@ -1,0 +1,21 @@
+"""Published peaks of the chips the benchmark runs on, keyed by JAX's
+``device_kind``. A device that is not here is an error, never a default."""
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops_per_s": 197e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+        "source": "Google Cloud documentation, 'TPU v5e' "
+                  "(cloud.google.com/tpu/docs/v5e): 197 TFLOP/s bf16, "
+                  "16 GB HBM2 at 819 GB/s",
+    },
+}
+
+
+def lookup(device_kind: str) -> dict:
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
