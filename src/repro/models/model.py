@@ -277,14 +277,19 @@ def build_decode_step(cfg: ModelConfig, flags: RunFlags,
 
     ``external_rows=True`` takes the Engram rows as an argument — the
     serving engine's prefetch path (retrieval dispatched as its own call
-    before the decode step is enqueued, per the paper's §4.3)."""
+    before the decode step is enqueued, per the paper's §4.3).
+
+    Both forms are named ``decode_step``, so a jitted step lowers as
+    ``jit_decode_step`` and its device events carry that name."""
     assert not cfg.is_encoder
 
     if external_rows:
-        return lambda params, state, token, rows: _decode_one(
-            cfg, flags, params, state, token, rows)
-    return lambda params, state, token: _decode_one(cfg, flags, params,
-                                                    state, token)
+        def decode_step(params, state, token, rows):
+            return _decode_one(cfg, flags, params, state, token, rows)
+    else:
+        def decode_step(params, state, token):
+            return _decode_one(cfg, flags, params, state, token)
+    return decode_step
 
 
 def build_multitoken_decode(cfg: ModelConfig, flags: RunFlags,

@@ -541,8 +541,10 @@ class TableFetcher:
         self.tables = tables
         hd = self.hd
         if impl == "take":
-            self._gather = jax.jit(lambda t, g: jnp.take(
-                t.reshape(-1, t.shape[-1]), g, axis=0)[:, :hd])
+            def engram_row_gather(t, g):
+                return jnp.take(t.reshape(-1, t.shape[-1]), g,
+                                axis=0)[:, :hd]
+            self._gather = jax.jit(engram_row_gather)
         else:
             self._gather = lambda t, g: gather_rows_padded(
                 t, g, width=hd, interpret=interpret)

@@ -60,6 +60,14 @@ tests/test_hotpath.py, chip_smoke.py). On the CPU backend the guard is
 inert (host and device share memory), so the counter is the enforced
 budget there; on a TPU the guard raises on any other pull.
 
+Host spans: each wave opens ``jax.profiler.TraceAnnotation`` spans, one
+per wave or group and never per key, that a profiler trace records
+beside the device's programs: ``repro.step`` (runtime), ``repro.admit`` and
+``repro.admit.group``, ``repro.decode``, ``repro.store.charge``,
+``repro.store.stall`` (a modelled stall slept into wall time) and
+``repro.sync`` (``_host``). With no profiler running a span costs under
+a microsecond.
+
 Pool-tier emulation: on real hardware the Engram fetch either hides inside
 the prefetch window or stalls the step (paper §3.2). The engine delegates
 that entirely to the tiered ``EngramStore`` subsystem (pool/store.py): a
@@ -86,6 +94,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..configs.base import ModelConfig, SpecConfig
 from ..core.engram import retrieve
@@ -94,8 +103,8 @@ from ..core.hashing import (block_engram_indices, block_engram_keys,
                             engram_indices, host_block_keys,
                             pack_segment_keys, prefix_chain_keys)
 from ..models.model import (build_chunk_prefill, build_decode_step,
-                            build_prefill_step, init_decode_state,
-                            init_params)
+                            build_prefill_step, init_params)
+from ..models.model import init_decode_state as _init_decode_state
 from ..models.transformer import RunFlags
 from ..pool.kvpool import KVPagePool, PoolArbiter
 from ..pool.scheduler import PrefetchScheduler
@@ -203,6 +212,7 @@ class EngineStats:
     requests_completed: int = 0
     requests_cancelled: int = 0
     ttft_s_sum: float = 0.0          # summed submit -> first-token latency
+    queue_wait_s_sum: float = 0.0    # summed submit -> admission start
     # --- speculation ------------------------------------------------------
     spec_waves: int = 0              # verify waves run
     proposed_tokens: int = 0         # drafts proposed (k per live slot-wave)
@@ -469,10 +479,11 @@ class Engine:
         # jitted fused index+key fns: keys are packed on-device (one int64
         # (B, S, L, T) tensor covers every Engram layer's stream), so each
         # charged wave costs ONE host sync instead of sync + L Python packs
-        self._decode_keys = (jax.jit(
-            lambda last, tok: decode_engram_keys(cfg.engram, last, tok,
-                                                 self._n_eng))
-            if self._pool_mode else None)
+        def decode_keys(last, tok):
+            return decode_engram_keys(cfg.engram, last, tok, self._n_eng)
+
+        self._decode_keys = jax.jit(decode_keys) if self._pool_mode \
+            else None
         self._wave_sync = (jax.jit(self._wave_sync_fn)
                            if self._pool_mode else None)
         # unpadded prefill caches: update_slots writes them at [0, S) of
@@ -548,9 +559,10 @@ class Engine:
                     build_verifier(cfg, flags, external_rows=True)),
                     donate_argnums=(1,))
                 if self._pool_mode:
-                    self._block_keys = jax.jit(
-                        lambda last, block: block_engram_keys(
-                            cfg.engram, last, block, self._n_eng))
+                    def block_keys(last, block):
+                        return block_engram_keys(cfg.engram, last, block,
+                                                 self._n_eng)
+                    self._block_keys = jax.jit(block_keys)
                 self._block_prefetch = jax.jit(self._block_prefetch_fn)
 
         self.state = self._fresh_state(max_batch)
@@ -562,7 +574,8 @@ class Engine:
         self.stats = EngineStats()
         self._rid = int(rid_start)
         self._runtime = None
-        self._step_times: list[float] = []
+        # wall time of the last waves: the stall model's hideable window
+        self._step_times: deque[float] = deque(maxlen=32)
         if step_latency_hint_s:
             self._step_times.append(step_latency_hint_s)
         # --- single-sync hot-path state ---------------------------------
@@ -618,8 +631,11 @@ class Engine:
         concatenated from per-layer zeros: the KV cache twice over."""
         out = None if self.device is None else \
             jax.sharding.SingleDeviceSharding(self.device)
-        return jax.jit(lambda: init_decode_state(
-            self.cfg, self.flags, batch, self.max_len), out_shardings=out)()
+
+        def init_decode_state():
+            return _init_decode_state(self.cfg, self.flags, batch,
+                                      self.max_len)
+        return jax.jit(init_decode_state, out_shardings=out)()
 
     # ------------------------------------------------------------ public API
 
@@ -759,7 +775,8 @@ class Engine:
         ``jax.transfer_guard_device_to_host("disallow")`` and still let
         this one pull through — any stray sync elsewhere raises."""
         self.stats.d2h_pulls += 1
-        with jax.transfer_guard_device_to_host("allow"):
+        with TraceAnnotation("repro.sync"), \
+                jax.transfer_guard_device_to_host("allow"):
             return np.asarray(arr)
 
     # ---------------------------------------------------------- prefill path
@@ -803,7 +820,6 @@ class Engine:
         tuples — the runtime turns them into ``TokenEvent`` streams."""
         if self.prefill_chunk is not None:
             return self._admit_chunked()
-        events = []
         fills = []
         if self.slo_policy is not None:
             # SLO admission: restores complete + preemption may free slots
@@ -812,7 +828,7 @@ class Engine:
                 self.queue.remove(req)
                 fills.append((self._free.popleft(), req))
             if not fills:
-                return events
+                return []
         elif self.idle_spill_tokens is not None:
             # long-context spill: complete last wave's restores, park
             # eligible long-running slots when the queue outstrips the
@@ -831,12 +847,20 @@ class Engine:
                     break
                 self._begin_restore(entry, self._free.popleft())
             if not fills:
-                return events
+                return []
         else:
             if not (self._free and self.queue):
-                return events
+                return []
             while self._free and self.queue:
                 fills.append((self._free.popleft(), self.queue.popleft()))
+        with TraceAnnotation("repro.admit", n=len(fills)):
+            return self._admit_groups(fills)
+
+    def _admit_groups(self, fills: list) -> list:
+        """Prefill ``fills`` (``(slot, request)`` pairs), one admission
+        group per prompt bucket, then charge the wave's prompt keys to the
+        store once."""
+        events = []
         groups: dict[int, list] = {}
         for slot, req in fills:
             S = _bucket(len(req.prompt), self.prompt_bucket)
@@ -844,8 +868,6 @@ class Engine:
         charge = [[] for _ in range(self._n_eng)] if self._pool_mode else None
         for S, group in sorted(groups.items()):
             n = len(group)
-            self.cursor.next_wave()
-            t_g = time.perf_counter()
             # pad the group batch to a power of two: admission traces stay
             # O(log max_batch) shapes per prompt bucket instead of one per
             # group size (a churny serve loop would recompile every wave).
@@ -853,56 +875,64 @@ class Engine:
             # the state write is dropped — and their keys/tokens are
             # sliced off on the host.
             n_pad = 1 << (n - 1).bit_length()
-            buf = self._prompt_view(n_pad, S)
-            lens = np.ones((n_pad,), np.int32)
-            for r, (_, req) in enumerate(group):
-                buf[r, :len(req.prompt)] = req.prompt
-                lens[r] = len(req.prompt)
-            # prefill compute accounting: the group executes every one of
-            # its n_pad x S token-positions — right-pad and pow2 pad rows
-            # included — which is exactly the waste chunking reclaims
-            useful = int(lens[:n].sum())
-            self.stats.prefill_waves += 1
-            self.stats.prefill_tokens += useful
-            self.stats.prefill_pad_tokens += n_pad * S - useful
-            emu_s = None
-            if self.emulate_step_s is not None:
-                # one bucketed multi-slot prefill: flat one batched step,
-                # or compute-proportional under emu_prefill_scaled
-                emu_s = self._prefill_step_s(n_pad * S)
-                self.stats.emu_time_s += emu_s
-            slots_j = jnp.asarray([s for s, _ in group]
-                                  + [self.max_batch] * (n_pad - n),
-                                  jnp.int32)
-            batch = {"tokens": jnp.asarray(buf),
-                     "lengths": jnp.asarray(lens)}
-            self.state, self.tokens, packed = self._admit_wave(
-                self.params, self.state, self.tokens, batch, slots_j)
-            packed = self._host(packed)          # ONE pull per group
-            toks = packed[:n]
-            if self._pool_mode:
-                pk = packed[n_pad:].reshape(n_pad, S, self._n_eng, -1)[:n]
+            with TraceAnnotation("repro.admit.group", S=S, n=n, n_pad=n_pad,
+                                 rids=" ".join(str(r.rid) for _, r in group)):
+                self.cursor.next_wave()
+                t_g = time.perf_counter()
+                buf = self._prompt_view(n_pad, S)
+                lens = np.ones((n_pad,), np.int32)
                 for r, (_, req) in enumerate(group):
-                    live = pk[r, :lens[r]]       # drop right-pad positions
-                    for j in range(self._n_eng):
-                        charge[j].append(live[:, j, :].reshape(-1))
-            t_now = time.perf_counter()
-            # the group's prefill is one batched step on the timeline
-            self.cursor.advance(emu_s if emu_s is not None else t_now - t_g)
-            for r, (slot, req) in enumerate(group):
-                tok = int(toks[r])
-                req.out.append(tok)
-                req.first_token_s = t_now
-                req.status = "running"
-                self.slots[slot] = req
-                self._tokens_host[slot] = tok
-                self.stats.prefills += 1
-                self.stats.generated_tokens += 1
-                self.stats.ttft_s_sum += t_now - req.submitted_s
-                if self.proposer is not None:
-                    self.proposer.begin(slot, req.prompt + req.out)
-                events.append((req, [tok], self._finish_if_done(slot),
-                               len(req.out) - 1))
+                    buf[r, :len(req.prompt)] = req.prompt
+                    lens[r] = len(req.prompt)
+                    self.stats.queue_wait_s_sum += t_g - req.submitted_s
+                # prefill compute accounting: the group executes every one
+                # of its n_pad x S token-positions — right-pad and pow2 pad
+                # rows included — which is exactly the waste chunking
+                # reclaims
+                useful = int(lens[:n].sum())
+                self.stats.prefill_waves += 1
+                self.stats.prefill_tokens += useful
+                self.stats.prefill_pad_tokens += n_pad * S - useful
+                emu_s = None
+                if self.emulate_step_s is not None:
+                    # one bucketed multi-slot prefill: flat one batched
+                    # step, or compute-proportional under emu_prefill_scaled
+                    emu_s = self._prefill_step_s(n_pad * S)
+                    self.stats.emu_time_s += emu_s
+                slots_j = jnp.asarray([s for s, _ in group]
+                                      + [self.max_batch] * (n_pad - n),
+                                      jnp.int32)
+                batch = {"tokens": jnp.asarray(buf),
+                         "lengths": jnp.asarray(lens)}
+                self.state, self.tokens, packed = self._admit_wave(
+                    self.params, self.state, self.tokens, batch, slots_j)
+                packed = self._host(packed)          # ONE pull per group
+                toks = packed[:n]
+                if self._pool_mode:
+                    pk = packed[n_pad:].reshape(n_pad, S, self._n_eng,
+                                                -1)[:n]
+                    for r, (_, req) in enumerate(group):
+                        live = pk[r, :lens[r]]   # drop right-pad positions
+                        for j in range(self._n_eng):
+                            charge[j].append(live[:, j, :].reshape(-1))
+                t_now = time.perf_counter()
+                # the group's prefill is one batched step on the timeline
+                self.cursor.advance(emu_s if emu_s is not None
+                                    else t_now - t_g)
+                for r, (slot, req) in enumerate(group):
+                    tok = int(toks[r])
+                    req.out.append(tok)
+                    req.first_token_s = t_now
+                    req.status = "running"
+                    self.slots[slot] = req
+                    self._tokens_host[slot] = tok
+                    self.stats.prefills += 1
+                    self.stats.generated_tokens += 1
+                    self.stats.ttft_s_sum += t_now - req.submitted_s
+                    if self.proposer is not None:
+                        self.proposer.begin(slot, req.prompt + req.out)
+                    events.append((req, [tok], self._finish_if_done(slot),
+                                   len(req.out) - 1))
         if self._pool_mode:
             # one fused charge: the admission wave's full prompt-key
             # stream per layer (a configured hot-row cache warms on it)
@@ -939,18 +969,23 @@ class Engine:
         Wave primitive: returns no events — a job's first token is
         emitted by the chunk wave that finishes its prompt."""
         if self.slo_policy is not None:
-            for req in self._overload_admit():
+            reqs = self._overload_admit()
+            for req in reqs:
                 self.queue.remove(req)
-                self._claim_job(req, self._free.popleft())
-            return []
-        while self._free and self.queue:
-            self._claim_job(self.queue.popleft(), self._free.popleft())
+        else:
+            reqs = [self.queue.popleft()
+                    for _ in range(min(len(self._free), len(self.queue)))]
+        if reqs:
+            with TraceAnnotation("repro.admit", n=len(reqs)):
+                for req in reqs:
+                    self._claim_job(req, self._free.popleft())
         return []
 
     def _claim_job(self, req: Request, slot: int) -> None:
         """Claim one free slot as a ``_PrefillJob`` (with the prefix-cache
         lookup + restorable-depth booking when configured)."""
         C = self.prefill_chunk
+        self.stats.queue_wait_s_sum += time.perf_counter() - req.submitted_s
         job = _PrefillJob(req=req, slot=slot)
         if self.prefix_cache is not None:
             job.chain = prefix_chain_keys(req.prompt, C)
@@ -1028,121 +1063,122 @@ class Engine:
             return []
         jobs = [self._prefill_jobs[s] for s in sorted(self._prefill_jobs)]
         C = self.prefill_chunk
-        t0 = time.perf_counter()
-        self.cursor.next_wave()
-        # settle the inter-wave bookings NEWEST-FIRST: Link.refund only
-        # rolls back the tail, and the bookings were issued in job order,
-        # so LIFO unwinds the whole run (the _propose_block doctrine) —
-        # the wave re-charges through the normal path below
-        for job in jobs[::-1]:
-            for tr in job.resv[::-1]:
-                self.clock.refund(tr)
-            job.resv.clear()
-        for job in jobs:
-            if not job.started:
-                if job.restore is not None and job.restore_bytes:
-                    # the prefix hit's tier fetch, re-priced at this
-                    # wave's timeline position; the snapshot must be on
-                    # device before the chunk computes, so the transfer's
-                    # completion is a charged stall
-                    tr = self._reserve_bytes(job.restore_bytes)
-                    if tr is not None and tr.end_s > self.cursor.now_s:
-                        stall = tr.end_s - self.cursor.now_s
-                        self.stats.stall_s += stall
-                        self.stats.emu_time_s += stall
-                        self.cursor.advance(stall)
-                self._start_job(job)
-        n = len(jobs)
-        # pow2 row padding: O(log max_batch) unroll traces, not one per
-        # job count (same admission-trace argument as the legacy groups)
-        n_pad = 1 << (n - 1).bit_length()
-        buf = self._prompt_view(n_pad, C)
-        lens = np.zeros((n_pad,), np.int32)
-        for r, job in enumerate(jobs):
-            take = min(C, len(job.req.prompt) - job.pos)
-            buf[r, :take] = job.req.prompt[job.pos:job.pos + take]
-            lens[r] = take
-        slots_j = jnp.asarray([j.slot for j in jobs]
-                              + [self.max_batch] * (n_pad - n), jnp.int32)
-        self.state, self.tokens, packed = self._chunk_wave_jit(
-            self.params, self.state, self.tokens, jnp.asarray(buf),
-            jnp.asarray(lens), slots_j)
-        packed = self._host(packed)            # ONE pull per chunk wave
-        toks = packed[:n_pad]
-        # prefill compute accounting: the unroll executes n_pad x C
-        # token-positions; pad = pow2 rows + each job's ragged tail steps
-        useful = int(lens[:n].sum())
-        self.stats.prefill_waves += 1
-        self.stats.prefill_tokens += useful
-        self.stats.prefill_pad_tokens += n_pad * C - useful
-        emu_s = None
-        if self.emulate_step_s is not None:
-            emu_s = self._prefill_step_s(n_pad * C)
-            self.stats.emu_time_s += emu_s
-        if self._pool_mode:
-            pk = packed[n_pad:].reshape(n_pad, C, self._n_eng, -1)
-            charge = [[] for _ in range(self._n_eng)]
-            for r in range(n):
-                live = pk[r, :lens[r]]         # drop ragged-tail positions
-                for j in range(self._n_eng):
-                    charge[j].append(live[:, j, :].reshape(-1))
-            self._charge_wave([np.concatenate(c) for c in charge],
-                              step_s=emu_s)
-        t_now = time.perf_counter()
-        self.cursor.advance(emu_s if emu_s is not None else t_now - t0)
-        self._step_times.append(time.perf_counter() - t0)
-        reserve = getattr(self.store, "reserve_prefetch", None) \
-            if self._pool_mode else None
-        events = []
-        t_v = self.cursor.now_s
-        for r, job in enumerate(jobs):
-            job.pos += int(lens[r])
-            req = job.req
-            done_prompt = job.pos >= len(req.prompt)
-            # spill the completed block boundary: the state at job.pos IS
-            # the boundary state (KV is positional; a finishing full-block
-            # wave lands exactly on one too) — future/concurrent requests
-            # sharing the prefix fetch it instead of recomputing
-            bi = job.pos // C - 1
-            if (self.prefix_cache is not None and job.pos % C == 0
-                    and 0 <= bi < len(job.chain)
-                    and job.chain[bi] not in self.prefix_cache):
-                with jax.transfer_guard_device_to_host("allow"):
-                    snap, nbytes = extract_prefix(self.state, job.slot,
-                                                  job.pos)
-                self.stats.d2h_pulls += 1      # the spill's host snapshot
-                if self.prefix_cache.insert(job.chain[bi], snap, job.pos,
-                                            nbytes):
-                    self._reserve_bytes(nbytes)   # write-behind spill
-            if done_prompt:
-                tok = int(toks[r])
-                req.out.append(tok)
-                req.first_token_s = t_now
-                req.first_token_v = t_v
-                self.slots[job.slot] = req
-                self._tokens_host[job.slot] = tok
-                self._prefill_jobs.pop(job.slot)
-                self.stats.prefills += 1
-                self.stats.generated_tokens += 1
-                self.stats.ttft_s_sum += t_now - req.submitted_s
-                self.stats.ttft_v_sum += t_v - req.submitted_v
-                if self.proposer is not None:
-                    self.proposer.begin(job.slot, req.prompt + req.out)
-                events.append((req, [tok], self._finish_if_done(job.slot),
-                               len(req.out) - 1))
-                # the previous decode wave's prefetched keys predate this
-                # slot going live — force a recompute next decode wave
-                self._next_keys = None
-            elif reserve is not None:
-                # book the NEXT chunk's engram prefetch now — in flight
-                # between waves, refunded (LIFO) and re-priced with the
-                # real keys at the next wave, or refunded outright by a
-                # mid-prefill cancel
-                nxt = min(C, len(req.prompt) - job.pos)
-                tr = reserve(nxt * self.cfg.engram.n_tables * self._n_eng)
-                if tr is not None:
-                    job.resv.append(tr)
-        return events
+        with TraceAnnotation("repro.admit", n=len(jobs)):
+            t0 = time.perf_counter()
+            self.cursor.next_wave()
+            # settle the inter-wave bookings NEWEST-FIRST: Link.refund only
+            # rolls back the tail, and the bookings were issued in job order,
+            # so LIFO unwinds the whole run (the _propose_block doctrine) —
+            # the wave re-charges through the normal path below
+            for job in jobs[::-1]:
+                for tr in job.resv[::-1]:
+                    self.clock.refund(tr)
+                job.resv.clear()
+            for job in jobs:
+                if not job.started:
+                    if job.restore is not None and job.restore_bytes:
+                        # the prefix hit's tier fetch, re-priced at this
+                        # wave's timeline position; the snapshot must be on
+                        # device before the chunk computes, so the transfer's
+                        # completion is a charged stall
+                        tr = self._reserve_bytes(job.restore_bytes)
+                        if tr is not None and tr.end_s > self.cursor.now_s:
+                            stall = tr.end_s - self.cursor.now_s
+                            self.stats.stall_s += stall
+                            self.stats.emu_time_s += stall
+                            self.cursor.advance(stall)
+                    self._start_job(job)
+            n = len(jobs)
+            # pow2 row padding: O(log max_batch) unroll traces, not one per
+            # job count (same admission-trace argument as the legacy groups)
+            n_pad = 1 << (n - 1).bit_length()
+            buf = self._prompt_view(n_pad, C)
+            lens = np.zeros((n_pad,), np.int32)
+            for r, job in enumerate(jobs):
+                take = min(C, len(job.req.prompt) - job.pos)
+                buf[r, :take] = job.req.prompt[job.pos:job.pos + take]
+                lens[r] = take
+            slots_j = jnp.asarray([j.slot for j in jobs]
+                                  + [self.max_batch] * (n_pad - n), jnp.int32)
+            self.state, self.tokens, packed = self._chunk_wave_jit(
+                self.params, self.state, self.tokens, jnp.asarray(buf),
+                jnp.asarray(lens), slots_j)
+            packed = self._host(packed)            # ONE pull per chunk wave
+            toks = packed[:n_pad]
+            # prefill compute accounting: the unroll executes n_pad x C
+            # token-positions; pad = pow2 rows + each job's ragged tail steps
+            useful = int(lens[:n].sum())
+            self.stats.prefill_waves += 1
+            self.stats.prefill_tokens += useful
+            self.stats.prefill_pad_tokens += n_pad * C - useful
+            emu_s = None
+            if self.emulate_step_s is not None:
+                emu_s = self._prefill_step_s(n_pad * C)
+                self.stats.emu_time_s += emu_s
+            if self._pool_mode:
+                pk = packed[n_pad:].reshape(n_pad, C, self._n_eng, -1)
+                charge = [[] for _ in range(self._n_eng)]
+                for r in range(n):
+                    live = pk[r, :lens[r]]         # drop ragged-tail positions
+                    for j in range(self._n_eng):
+                        charge[j].append(live[:, j, :].reshape(-1))
+                self._charge_wave([np.concatenate(c) for c in charge],
+                                  step_s=emu_s)
+            t_now = time.perf_counter()
+            self.cursor.advance(emu_s if emu_s is not None else t_now - t0)
+            self._step_times.append(time.perf_counter() - t0)
+            reserve = getattr(self.store, "reserve_prefetch", None) \
+                if self._pool_mode else None
+            events = []
+            t_v = self.cursor.now_s
+            for r, job in enumerate(jobs):
+                job.pos += int(lens[r])
+                req = job.req
+                done_prompt = job.pos >= len(req.prompt)
+                # spill the completed block boundary: the state at job.pos IS
+                # the boundary state (KV is positional; a finishing full-block
+                # wave lands exactly on one too) — future/concurrent requests
+                # sharing the prefix fetch it instead of recomputing
+                bi = job.pos // C - 1
+                if (self.prefix_cache is not None and job.pos % C == 0
+                        and 0 <= bi < len(job.chain)
+                        and job.chain[bi] not in self.prefix_cache):
+                    with jax.transfer_guard_device_to_host("allow"):
+                        snap, nbytes = extract_prefix(self.state, job.slot,
+                                                      job.pos)
+                    self.stats.d2h_pulls += 1      # the spill's host snapshot
+                    if self.prefix_cache.insert(job.chain[bi], snap, job.pos,
+                                                nbytes):
+                        self._reserve_bytes(nbytes)   # write-behind spill
+                if done_prompt:
+                    tok = int(toks[r])
+                    req.out.append(tok)
+                    req.first_token_s = t_now
+                    req.first_token_v = t_v
+                    self.slots[job.slot] = req
+                    self._tokens_host[job.slot] = tok
+                    self._prefill_jobs.pop(job.slot)
+                    self.stats.prefills += 1
+                    self.stats.generated_tokens += 1
+                    self.stats.ttft_s_sum += t_now - req.submitted_s
+                    self.stats.ttft_v_sum += t_v - req.submitted_v
+                    if self.proposer is not None:
+                        self.proposer.begin(job.slot, req.prompt + req.out)
+                    events.append((req, [tok], self._finish_if_done(job.slot),
+                                   len(req.out) - 1))
+                    # the previous decode wave's prefetched keys predate this
+                    # slot going live — force a recompute next decode wave
+                    self._next_keys = None
+                elif reserve is not None:
+                    # book the NEXT chunk's engram prefetch now — in flight
+                    # between waves, refunded (LIFO) and re-priced with the
+                    # real keys at the next wave, or refunded outright by a
+                    # mid-prefill cancel
+                    nxt = min(C, len(req.prompt) - job.pos)
+                    tr = reserve(nxt * self.cfg.engram.n_tables * self._n_eng)
+                    if tr is not None:
+                        job.resv.append(tr)
+            return events
 
     # ----------------------------------------------------------- decode path
 
@@ -1199,81 +1235,83 @@ class Engine:
         active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
             return []
-        t0 = time.perf_counter()
-        self.cursor.next_wave()
-        B = self.max_batch
-        if self.emulate_step_s is not None:
-            self.stats.emu_time_s += self.emulate_step_s
-        rows = None
-        if self._pool_mode:
-            # the active slots' real segment-key stream: the store's cache
-            # measures hit rates on it, the scheduler charges the overshoot.
-            # Steady state reuses the keys prefetched by the previous
-            # wave's fused sync; only post-admission waves recompute.
-            keys = self._next_keys
-            if keys is None:
-                keys = self._host(self._decode_keys(
-                    self.state["last_tokens"], self.tokens))
-            self._next_keys = None
-            act = keys[np.asarray(active)]               # (A, 1, L, T)
-            per_layer = [act[:, :, j, :].reshape(-1)
-                         for j in range(self._n_eng)]
-            fetch = self._miss_fetches(keys) \
-                if self._decode_ext is not None else None
-            rows = self._charge_wave(per_layer, fetch=fetch)
-        elif self._decode_ext is not None:
-            # the paper's prefetch: retrieval dispatched as its own call,
-            # materialized through the store (prefetch -> gather)
-            fetch = lambda: self._prefetch(self.params,
-                                           self.state["last_tokens"],
-                                           self.tokens)
-            rows = self.store.gather(
-                self.store.prefetch(len(active), fetch=fetch))
-        if self.prefill_chunk is not None and self._prefill_jobs:
-            # prefill jobs in flight: gate the state update by liveness so
-            # their partial KV / positions are untouched by this wave
-            live = np.zeros((B,), np.bool_)
-            live[np.asarray(active)] = True
-            live_j = jnp.asarray(live)
-            if self._decode_ext is not None:
-                logits, self.state = self._decode_ext_gated(
-                    self.params, self.state, self.tokens, rows, live_j)
+        with TraceAnnotation("repro.decode", live=len(active)):
+            t0 = time.perf_counter()
+            self.cursor.next_wave()
+            B = self.max_batch
+            if self.emulate_step_s is not None:
+                self.stats.emu_time_s += self.emulate_step_s
+            rows = None
+            if self._pool_mode:
+                # the active slots' real segment-key stream: the store's
+                # cache measures hit rates on it, the scheduler charges the
+                # overshoot.
+                # Steady state reuses the keys prefetched by the previous
+                # wave's fused sync; only post-admission waves recompute.
+                keys = self._next_keys
+                if keys is None:
+                    keys = self._host(self._decode_keys(
+                        self.state["last_tokens"], self.tokens))
+                self._next_keys = None
+                act = keys[np.asarray(active)]               # (A, 1, L, T)
+                per_layer = [act[:, :, j, :].reshape(-1)
+                             for j in range(self._n_eng)]
+                fetch = self._miss_fetches(keys) \
+                    if self._decode_ext is not None else None
+                rows = self._charge_wave(per_layer, fetch=fetch)
+            elif self._decode_ext is not None:
+                # the paper's prefetch: retrieval dispatched as its own call,
+                # materialized through the store (prefetch -> gather)
+                fetch = lambda: self._prefetch(self.params,
+                                               self.state["last_tokens"],
+                                               self.tokens)
+                rows = self.store.gather(
+                    self.store.prefetch(len(active), fetch=fetch))
+            if self.prefill_chunk is not None and self._prefill_jobs:
+                # prefill jobs in flight: gate the state update by liveness so
+                # their partial KV / positions are untouched by this wave
+                live = np.zeros((B,), np.bool_)
+                live[np.asarray(active)] = True
+                live_j = jnp.asarray(live)
+                if self._decode_ext is not None:
+                    logits, self.state = self._decode_ext_gated(
+                        self.params, self.state, self.tokens, rows, live_j)
+                else:
+                    logits, self.state = self._decode_gated(
+                        self.params, self.state, self.tokens, live_j)
+            elif self._decode_ext is not None:
+                logits, self.state = self._decode_ext(self.params, self.state,
+                                                      self.tokens, rows)
             else:
-                logits, self.state = self._decode_gated(
-                    self.params, self.state, self.tokens, live_j)
-        elif self._decode_ext is not None:
-            logits, self.state = self._decode_ext(self.params, self.state,
-                                                  self.tokens, rows)
-        else:
-            logits, self.state = self._decode(self.params, self.state,
-                                              self.tokens)
-        new_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        self.tokens = new_tok
-        if self._pool_mode:
-            # the wave's ONE sync: sampled tokens + next wave's keys fused
-            sync = self._host(self._wave_sync(self.state["last_tokens"],
-                                              new_tok))
-            toks = sync[:B]
-            self._next_keys = sync[B:].reshape(B, 1, self._n_eng, -1)
-        else:
-            toks = self._host(new_tok)
-        self._tokens_host[:] = toks
-        dt = time.perf_counter() - t0
-        self._step_times.append(dt)
-        # the wave's compute on the timeline (real runs already slept the
-        # stall inside _charge_wave, so dt covers it; emulated runs add
-        # the stall advance in _charge_wave itself)
-        self.cursor.advance(self.emulate_step_s
-                            if self.emulate_step_s is not None else dt)
-        self.stats.decode_steps += 1
-        events = []
-        for i in active:
-            req = self.slots[i]
-            req.out.append(int(toks[i]))
-            self.stats.generated_tokens += 1
-            events.append((req, [int(toks[i])], self._finish_if_done(i),
-                           len(req.out) - 1))
-        return events
+                logits, self.state = self._decode(self.params, self.state,
+                                                  self.tokens)
+            new_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            self.tokens = new_tok
+            if self._pool_mode:
+                # the wave's ONE sync: sampled tokens + next wave's keys fused
+                sync = self._host(self._wave_sync(self.state["last_tokens"],
+                                                  new_tok))
+                toks = sync[:B]
+                self._next_keys = sync[B:].reshape(B, 1, self._n_eng, -1)
+            else:
+                toks = self._host(new_tok)
+            self._tokens_host[:] = toks
+            dt = time.perf_counter() - t0
+            self._step_times.append(dt)
+            # the wave's compute on the timeline (real runs already slept the
+            # stall inside _charge_wave, so dt covers it; emulated runs add
+            # the stall advance in _charge_wave itself)
+            self.cursor.advance(self.emulate_step_s
+                                if self.emulate_step_s is not None else dt)
+            self.stats.decode_steps += 1
+            events = []
+            for i in active:
+                req = self.slots[i]
+                req.out.append(int(toks[i]))
+                self.stats.generated_tokens += 1
+                events.append((req, [int(toks[i])], self._finish_if_done(i),
+                               len(req.out) - 1))
+            return events
 
     # ------------------------------------------------------ speculate path
 
@@ -1292,13 +1330,13 @@ class Engine:
         """Wrap a verifier so its host-bound outputs — preds (B, m) and
         n_accept (B,) — come back as ONE (B, m+1) int32 verdict tensor:
         the speculative wave's single post-verify pull."""
-        def fused(params, state, block, rows=None):
+        def verify_step(params, state, block, rows=None):
             preds, n_accept, next_tok, new_state = (
                 verify(params, state, block, rows) if rows is not None
                 else verify(params, state, block))
             verdict = jnp.concatenate([preds, n_accept[:, None]], axis=1)
             return verdict, next_tok, new_state
-        return fused
+        return verify_step
 
     def _propose_block(self, active, k: int) -> tuple:
         """Build the wave's (B, m) block on the host: pending tokens from
@@ -1390,124 +1428,127 @@ class Engine:
         active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
             return []
-        t0 = time.perf_counter()
-        self.cursor.next_wave()
-        k = self.spec.max_draft
-        m = k + 1
-        B = self.max_batch
+        with TraceAnnotation("repro.decode", live=len(active)):
+            t0 = time.perf_counter()
+            self.cursor.next_wave()
+            k = self.spec.max_draft
+            m = k + 1
+            B = self.max_batch
 
-        block, pipe_hits, pipe_keys = self._propose_block(active, k)
-        block_j = jnp.asarray(block)
+            block, pipe_hits, pipe_keys = self._propose_block(active, k)
+            block_j = jnp.asarray(block)
 
-        # the verify pass costs ~one decode step (memory-bound) plus a
-        # small per-extra-token compute term
-        step_s = self._step_estimate_s()
-        verify_s = step_s * (1.0 + self.spec.verify_overhead * (m - 1))
-        if self.emulate_step_s is not None:
-            self.stats.emu_time_s += verify_s
+            # the verify pass costs ~one decode step (memory-bound) plus a
+            # small per-extra-token compute term
+            step_s = self._step_estimate_s()
+            verify_s = step_s * (1.0 + self.spec.verify_overhead * (m - 1))
+            if self.emulate_step_s is not None:
+                self.stats.emu_time_s += verify_s
 
-        spec_report = None
-        rows = None
-        if self.has_engram:
-            if self._pool_mode:
-                all_hit = bool(active) and \
-                    all(i in pipe_keys for i in active)
-                if all_hit:
-                    # SINGLE-SYNC wave: every live slot's block was
-                    # predicted last wave and its keys packed host-side
-                    # (bit-identical to the device path) — skip the
-                    # packed-key pull; the fused verdict is the wave's
-                    # only device->host transfer
-                    keys = np.zeros((B, m, self._n_eng,
-                                     self.cfg.engram.n_tables), np.int64)
-                    for i in active:
-                        keys[i] = pipe_keys[i]
-                else:
-                    # ONE packed pull covers every (position, slot, layer)
-                    # stream; numpy views replace the old per-cell Python
-                    # packing nest, and the scheduler dedups with one sort
-                    keys = self._host(self._block_keys(
-                        self.state["last_tokens"], block_j))  # (B,m,L,T)
-                act = np.asarray(active)
-                ka = keys[act]                               # (A,m,L,T)
-                keys_by_pos = [
-                    [ka[:, s, j, :].reshape(-1) for j in range(self._n_eng)]
-                    for s in range(m)]
-                # a fully pipelined block was issued a verify pass early;
-                # one straggler slot drags the fused fetch back to wave
-                # start, so the credit needs every live slot to have hit
-                early = verify_s if (active and
-                                     all(i in pipe_hits for i in active)) \
-                    else 0.0
-                spec_report = self.scheduler.speculative_wave(
-                    keys_by_pos, verify_s,
-                    slot_keys=ka.reshape(len(active), m, -1),
-                    slot_ids=active, early_issue_s=early)
-                fetches = self._miss_fetches(keys)
-                rows = [f() for f in fetches]
-            elif self._verify_ext is not None:
-                fetch = lambda: self._block_prefetch(
-                    self.params, self.state["last_tokens"], block_j)
-                rows = self.store.gather(
-                    self.store.prefetch(len(active) * m, fetch=fetch))
+            spec_report = None
+            rows = None
+            if self.has_engram:
+                if self._pool_mode:
+                    all_hit = bool(active) and \
+                        all(i in pipe_keys for i in active)
+                    if all_hit:
+                        # SINGLE-SYNC wave: every live slot's block was
+                        # predicted last wave and its keys packed host-side
+                        # (bit-identical to the device path) — skip the
+                        # packed-key pull; the fused verdict is the wave's
+                        # only device->host transfer
+                        keys = np.zeros((B, m, self._n_eng,
+                                         self.cfg.engram.n_tables), np.int64)
+                        for i in active:
+                            keys[i] = pipe_keys[i]
+                    else:
+                        # ONE packed pull covers every (position, slot, layer)
+                        # stream; numpy views replace the old per-cell Python
+                        # packing nest, and the scheduler dedups with one sort
+                        keys = self._host(self._block_keys(
+                            self.state["last_tokens"], block_j))  # (B,m,L,T)
+                    act = np.asarray(active)
+                    ka = keys[act]                               # (A,m,L,T)
+                    keys_by_pos = [
+                        [ka[:, s, j, :].reshape(-1)
+                         for j in range(self._n_eng)]
+                        for s in range(m)]
+                    # a fully pipelined block was issued a verify pass early;
+                    # one straggler slot drags the fused fetch back to wave
+                    # start, so the credit needs every live slot to have hit
+                    early = verify_s if (active and
+                                         all(i in pipe_hits for i in active)) \
+                        else 0.0
+                    with TraceAnnotation("repro.store.charge", keys=ka.size):
+                        spec_report = self.scheduler.speculative_wave(
+                            keys_by_pos, verify_s,
+                            slot_keys=ka.reshape(len(active), m, -1),
+                            slot_ids=active, early_issue_s=early)
+                        rows = [f() for f in self._miss_fetches(keys)]
+                elif self._verify_ext is not None:
+                    fetch = lambda: self._block_prefetch(
+                        self.params, self.state["last_tokens"], block_j)
+                    rows = self.store.gather(
+                        self.store.prefetch(len(active) * m, fetch=fetch))
 
-        if rows is not None:
-            verdict, next_tok, new_state = self._verify_ext(
-                self.params, self.state, block_j, rows)
-        else:
-            verdict, next_tok, new_state = self._verify(
-                self.params, self.state, block_j)
-        self.state = new_state
-        self.tokens = next_tok
-
-        if self.spec.pipeline:
-            # wave N+1's proposals, drafted while the verify is in flight
-            self._pipeline_proposals(active, block, k)
-
-        verdict = self._host(verdict)                  # (B, m+1)
-        preds_np = verdict[:, :m]
-        n_acc = verdict[:, m]
-        # host mirror of next_tok: preds[b, n_accept[b]] by construction
-        self._tokens_host[:] = preds_np[np.arange(B), n_acc]
-        if spec_report is not None:
-            acc_active = n_acc[np.asarray(active)]
-            n_keep = int(acc_active.max()) + 1
-            stall = self.scheduler.charge_spec(
-                spec_report, n_keep,
-                tokens_emitted=int((acc_active + 1).sum()),
-                n_keep_by_slot={i: int(n_acc[i]) + 1 for i in active})
-            self.stats.stall_s += stall
-            if self.emulate_step_s is None:
-                if stall > 0:
-                    time.sleep(stall)
+            if rows is not None:
+                verdict, next_tok, new_state = self._verify_ext(
+                    self.params, self.state, block_j, rows)
             else:
-                self.stats.emu_time_s += stall
-                self.cursor.advance(stall)
+                verdict, next_tok, new_state = self._verify(
+                    self.params, self.state, block_j)
+            self.state = new_state
+            self.tokens = next_tok
 
-        dt = time.perf_counter() - t0
-        self._step_times.append(dt)
-        self.cursor.advance(verify_s if self.emulate_step_s is not None
-                            else dt)
-        self.stats.decode_steps += 1
-        self.stats.spec_waves += 1
-        events = []
-        for i in active:
-            req = self.slots[i]
-            a = int(n_acc[i])
-            room = req.max_new - len(req.out)
-            emit = [int(t) for t in preds_np[i, :a + 1][:room]]
-            req.out.extend(emit)
-            self.stats.generated_tokens += len(emit)
-            self.stats.proposed_tokens += k
-            self.stats.accepted_tokens += a
-            by = self.stats.spec_by_class.setdefault(
-                req.klass or "uniform", {"proposed": 0, "accepted": 0})
-            by["proposed"] += k
-            by["accepted"] += a
-            self.proposer.observe(i, req.prompt + req.out)
-            events.append((req, emit, self._finish_if_done(i),
-                           len(req.out) - len(emit)))
-        return events
+            if self.spec.pipeline:
+                # wave N+1's proposals, drafted while the verify is in flight
+                self._pipeline_proposals(active, block, k)
+
+            verdict = self._host(verdict)                  # (B, m+1)
+            preds_np = verdict[:, :m]
+            n_acc = verdict[:, m]
+            # host mirror of next_tok: preds[b, n_accept[b]] by construction
+            self._tokens_host[:] = preds_np[np.arange(B), n_acc]
+            if spec_report is not None:
+                acc_active = n_acc[np.asarray(active)]
+                n_keep = int(acc_active.max()) + 1
+                with TraceAnnotation("repro.store.charge"):
+                    stall = self.scheduler.charge_spec(
+                        spec_report, n_keep,
+                        tokens_emitted=int((acc_active + 1).sum()),
+                        n_keep_by_slot={i: int(n_acc[i]) + 1
+                                        for i in active})
+                    self.stats.stall_s += stall
+                    if self.emulate_step_s is None:
+                        self._sleep_stall(stall)
+                    else:
+                        self.stats.emu_time_s += stall
+                        self.cursor.advance(stall)
+
+            dt = time.perf_counter() - t0
+            self._step_times.append(dt)
+            self.cursor.advance(verify_s if self.emulate_step_s is not None
+                                else dt)
+            self.stats.decode_steps += 1
+            self.stats.spec_waves += 1
+            events = []
+            for i in active:
+                req = self.slots[i]
+                a = int(n_acc[i])
+                room = req.max_new - len(req.out)
+                emit = [int(t) for t in preds_np[i, :a + 1][:room]]
+                req.out.extend(emit)
+                self.stats.generated_tokens += len(emit)
+                self.stats.proposed_tokens += k
+                self.stats.accepted_tokens += a
+                by = self.stats.spec_by_class.setdefault(
+                    req.klass or "uniform", {"proposed": 0, "accepted": 0})
+                by["proposed"] += k
+                by["accepted"] += a
+                self.proposer.observe(i, req.prompt + req.out)
+                events.append((req, emit, self._finish_if_done(i),
+                               len(req.out) - len(emit)))
+            return events
 
     def _finish_if_done(self, slot: int) -> bool:
         req = self.slots[slot]
@@ -1797,7 +1838,7 @@ class Engine:
             return self.emulate_step_s
         if not self._step_times:
             return 1e-3
-        return float(np.median(self._step_times[-32:]))
+        return float(np.median(self._step_times))
 
     def _prefill_step_s(self, executed_tokens: int) -> float:
         """Emulated cost of one prefill wave that executed
@@ -1853,17 +1894,27 @@ class Engine:
         list or a fused callable). ``step_s`` overrides the hideable
         window (a scaled prefill wave's compute is longer than one decode
         step, so its retrieval hides inside more)."""
-        report = self.scheduler.step(
-            keys_per_layer,
-            self._step_estimate_s() if step_s is None else step_s,
-            fetch=fetch)
-        self.stats.stall_s += report.stall_s
-        if self.emulate_step_s is None:
-            if report.stall_s > 0:
-                time.sleep(report.stall_s)
-        else:
-            self.stats.emu_time_s += report.stall_s
-            # emulated stalls advance the virtual cursor here; real stalls
-            # are slept and land in the wave's measured dt
-            self.cursor.advance(report.stall_s)
-        return report.gather(self.store) if fetch is not None else None
+        with TraceAnnotation("repro.store.charge",
+                             keys=sum(k.size for k in keys_per_layer)):
+            report = self.scheduler.step(
+                keys_per_layer,
+                self._step_estimate_s() if step_s is None else step_s,
+                fetch=fetch)
+            self.stats.stall_s += report.stall_s
+            if self.emulate_step_s is None:
+                self._sleep_stall(report.stall_s)
+            else:
+                self.stats.emu_time_s += report.stall_s
+                # emulated stalls advance the virtual cursor here; real
+                # stalls are slept and land in the wave's measured dt
+                self.cursor.advance(report.stall_s)
+            return report.gather(self.store) if fetch is not None else None
+
+    @staticmethod
+    def _sleep_stall(stall_s: float) -> None:
+        """Sleep a modelled pool-tier stall into wall time (real point),
+        under its own span: the time is the latency model's, not a
+        measured transfer."""
+        if stall_s > 0:
+            with TraceAnnotation("repro.store.stall", ms=stall_s * 1e3):
+                time.sleep(stall_s)

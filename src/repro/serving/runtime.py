@@ -29,6 +29,8 @@ import time
 from collections import deque
 from typing import Iterator, Optional
 
+from jax.profiler import TraceAnnotation
+
 from .engine import Engine, EngineStats, Request
 
 
@@ -181,17 +183,20 @@ class EngramRuntime:
         eng = self.engine
         t0 = time.perf_counter()
         waves = []
-        raw = eng._admit()
-        if raw:
-            waves.append((raw, eng.cursor.now_s))
-        if eng.prefill_chunk is not None:
-            raw = eng._chunk_wave()
+        with TraceAnnotation("repro.step",
+                             live=sum(s is not None for s in eng.slots),
+                             queued=len(eng.queue)):
+            raw = eng._admit()
             if raw:
                 waves.append((raw, eng.cursor.now_s))
-        raw = eng._spec_wave() if eng.spec is not None \
-            else eng._decode_wave()
-        if raw:
-            waves.append((raw, eng.cursor.now_s))
+            if eng.prefill_chunk is not None:
+                raw = eng._chunk_wave()
+                if raw:
+                    waves.append((raw, eng.cursor.now_s))
+            raw = eng._spec_wave() if eng.spec is not None \
+                else eng._decode_wave()
+            if raw:
+                waves.append((raw, eng.cursor.now_s))
         eng.stats.wall_s += time.perf_counter() - t0
         eng.stats.v_time_s = eng.cursor.now_s
         events = []
