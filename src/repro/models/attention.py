@@ -177,11 +177,42 @@ def attention(cfg: ModelConfig, params, h, positions, kind: str,
     return out, {"k": k, "v": v}
 
 
+def write_rows(buf, new, positions, layer=None):
+    """Write each slot's new row into its cache at the slot's position.
+
+    buf (B, S, ...) and new (B, 1, ...); positions (B,) are clamped into
+    [0, S) as ``dynamic_update_slice`` clamps them. With ``layer``, buf is
+    a layer stack (n, B, S, ...) and only layer ``layer``'s B rows are
+    written: a scatter of B rows that updates a donated stack in place,
+    where slicing the layer out and writing it back would copy the whole
+    layer's cache twice. Returns the updated buf."""
+    new = new.astype(buf.dtype)
+    if layer is None:
+        return jax.vmap(
+            lambda b, n, p: jax.lax.dynamic_update_slice_in_dim(b, n, p, axis=0)
+        )(buf, new, positions)
+
+    pos = jnp.clip(positions, 0, buf.shape[2] - 1)
+    return buf.at[layer, jnp.arange(buf.shape[1]), pos].set(new[:, 0])
+
+
+def layer_view(buf, layer=None):
+    """Layer ``layer`` of a stacked cache leaf (the leaf itself without
+    one). A dynamic index the compiler fuses into its consumers."""
+    if layer is None:
+        return buf
+    return jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
+
+
 def decode_attention(cfg: ModelConfig, params, h, cache, positions, kind: str,
-                     *, bf16_scores: bool = False,
+                     *, layer=None, bf16_scores: bool = False,
                      window_slice: bool = False):
     """Single-token decode. h (B,1,d); cache {k,v}: (B,Smax,Hkv,D);
     positions (B,) current index per sequence. Returns (out, new_cache).
+
+    ``layer``: the cache leaves are a layer stack (n,B,Smax,Hkv,D) and
+    this is layer ``layer`` of it; the new rows are written into the
+    stack (``write_rows``) and the whole stack is returned.
 
     ``window_slice``: sliding-window layers attend to a gathered
     window-sized cache slice instead of masking the full context — cuts
@@ -199,15 +230,14 @@ def decode_attention(cfg: ModelConfig, params, h, cache, positions, kind: str,
     k = apply_rope(k, positions[:, None], theta)
 
     # scatter new k/v at per-sequence positions
-    def upd(buf, new):
-        return jax.vmap(
-            lambda b, n, p: jax.lax.dynamic_update_slice_in_dim(b, n, p, axis=0)
-        )(buf, new, positions)
-
-    kc = upd(cache["k"], k.astype(cache["k"].dtype))
-    vc = upd(cache["v"], v.astype(cache["v"].dtype))
-    kc = shard(kc, "batch", "kv_seq", "kv_heads", None)
-    vc = shard(vc, "batch", "kv_seq", "kv_heads", None)
+    new_cache = {"k": write_rows(cache["k"], k, positions, layer),
+                 "v": write_rows(cache["v"], v, positions, layer)}
+    kc = shard(layer_view(new_cache["k"], layer),
+               "batch", "kv_seq", "kv_heads", None)
+    vc = shard(layer_view(new_cache["v"], layer),
+               "batch", "kv_seq", "kv_heads", None)
+    if layer is None:
+        new_cache = {"k": kc, "v": vc}
 
     S = kc.shape[1]
     window = cfg.window_size if kind == "local" else 0
@@ -229,7 +259,7 @@ def decode_attention(cfg: ModelConfig, params, h, cache, positions, kind: str,
     out = _sdpa(cfg, q, k_att, v_att, valid[:, None, :], bf16_scores)
     out = out.reshape(B, 1, hq * hd)
     out = out @ params["wo"]
-    return out, {"k": kc, "v": vc}
+    return out, new_cache
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from ..sharding.rules import shard
-from .attention import _chunk_attn, _mask, _sdpa
+from .attention import _chunk_attn, _mask, _sdpa, layer_view, write_rows
 from .layers import apply_rope, rmsnorm
 from .params import pd
 
@@ -84,10 +84,12 @@ def mla_attention(cfg: ModelConfig, params, h, positions, kind: str = "global",
 
 
 def mla_decode(cfg: ModelConfig, params, h, cache, positions,
-               *, bf16_scores: bool = False):
+               *, layer=None, bf16_scores: bool = False):
     """Absorbed decode on compressed cache.
 
     cache: c_kv (B,Smax,kv_lora), k_rope (B,Smax,rope). positions (B,).
+    ``layer``: the leaves are a layer stack and this is layer ``layer``
+    of it, as in ``attention.decode_attention``.
     ``bf16_scores``: f32 accumulation without materializing f32 cache
     copies (§Perf iteration 1)."""
     m, H = cfg.mla, cfg.n_heads
@@ -99,15 +101,15 @@ def mla_decode(cfg: ModelConfig, params, h, cache, positions,
     wuk = params["wuk"].reshape(m.kv_lora_rank, H, nope)
     q_lat = jnp.einsum("bshn,lhn->bshl", q_nope, wuk)          # (B,1,H,kv_lora)
 
-    def upd(buf, new):
-        return jax.vmap(
-            lambda b, n, p: jax.lax.dynamic_update_slice_in_dim(b, n, p, axis=0)
-        )(buf, new, positions)
-
-    ckv = upd(cache["c_kv"], c_kv_new.astype(cache["c_kv"].dtype))
-    krp = upd(cache["k_rope"], k_rope_new.squeeze(2).astype(cache["k_rope"].dtype))
-    ckv = shard(ckv, "batch", "kv_seq", None)
-    krp = shard(krp, "batch", "kv_seq", None)
+    new_cache = {
+        "c_kv": write_rows(cache["c_kv"], c_kv_new, positions, layer),
+        "k_rope": write_rows(cache["k_rope"], k_rope_new.squeeze(2),
+                             positions, layer)}
+    ckv = shard(layer_view(new_cache["c_kv"], layer), "batch", "kv_seq", None)
+    krp = shard(layer_view(new_cache["k_rope"], layer),
+                "batch", "kv_seq", None)
+    if layer is None:
+        new_cache = {"c_kv": ckv, "k_rope": krp}
 
     S = ckv.shape[1]
     scale = 1.0 / math.sqrt(nope + rope)
@@ -133,7 +135,7 @@ def mla_decode(cfg: ModelConfig, params, h, cache, positions,
     wuv = params["wuv"].reshape(m.kv_lora_rank, H, m.v_head_dim)
     out = jnp.einsum("bshl,lhv->bshv", out_lat.astype(h.dtype), wuv)
     out = out.reshape(B, 1, H * m.v_head_dim) @ params["wo"]
-    return out, {"c_kv": ckv, "k_rope": krp}
+    return out, new_cache
 
 
 def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from ..sharding.rules import shard
-from .attention import (attn_defs, attention, decode_attention, init_kv_cache)
+from .attention import (attn_defs, attention, decode_attention, init_kv_cache,
+                        layer_view)
 from .layers import mlp, mlp_defs, rmsnorm, rmsnorm_defs
 from .mamba import init_mamba_cache, mamba_defs, mamba_forward
 from .mla import init_mla_cache, mla_attention, mla_decode, mla_defs
@@ -139,11 +140,16 @@ def init_block_cache(cfg: ModelConfig, i: int, batch: int, max_len: int,
 
 
 def apply_block(cfg: ModelConfig, flags: RunFlags, sig: tuple, params, h,
-                positions, cache, mode: str):
+                positions, cache, mode: str, layer=None):
     """One transformer block. mode: train | prefill | decode.
 
     Returns (h, new_cache, aux). ``cache`` is None in train mode (recurrent
-    mixers start from zeros; attention keeps no state)."""
+    mixers start from zeros; attention keeps no state).
+
+    ``layer`` (decode only): ``cache`` is a layer stack and this block is
+    layer ``layer`` of it; the whole stack is returned, updated. Attention
+    writes only its new rows into it; a recurrent mixer replaces its whole
+    (small) state, which is written back at ``layer``."""
     t, kind, ffn = sig
     aux = jnp.zeros((), jnp.float32)
     pre = rmsnorm(params["ln1"], h, cfg.norm_eps)
@@ -151,12 +157,12 @@ def apply_block(cfg: ModelConfig, flags: RunFlags, sig: tuple, params, h,
         if mode == "decode":
             if cfg.attn_impl == "mla":
                 out, new_cache = mla_decode(cfg, params["mixer"], pre, cache,
-                                            positions,
+                                            positions, layer=layer,
                                             bf16_scores=flags.attn_bf16_scores)
             else:
                 out, new_cache = decode_attention(
                     cfg, params["mixer"], pre, cache, positions, kind,
-                    bf16_scores=flags.attn_bf16_scores,
+                    layer=layer, bf16_scores=flags.attn_bf16_scores,
                     window_slice=flags.decode_window_slice)
         else:
             if cfg.attn_impl == "mla":
@@ -172,14 +178,17 @@ def apply_block(cfg: ModelConfig, flags: RunFlags, sig: tuple, params, h,
                                     chunk_threshold=flags.chunk_threshold,
                                     bf16_scores=flags.attn_bf16_scores)
             new_cache = kv if mode == "prefill" else None
-    elif t == "mamba":
-        out, new_cache = mamba_forward(cfg, params["mixer"], pre, cache)
-    elif t == "mlstm":
-        out, new_cache = mlstm_forward(cfg, params["mixer"], pre, cache)
-    elif t == "slstm":
-        out, new_cache = slstm_forward(cfg, params["mixer"], pre, cache)
     else:
-        raise ValueError(t)
+        mixer = {"mamba": mamba_forward, "mlstm": mlstm_forward,
+                 "slstm": slstm_forward}.get(t)
+        if mixer is None:
+            raise ValueError(t)
+        c = jax.tree.map(lambda x: layer_view(x, layer), cache)
+        out, new_cache = mixer(cfg, params["mixer"], pre, c)
+        if layer is not None:
+            new_cache = jax.tree.map(
+                lambda x, n: jax.lax.dynamic_update_index_in_dim(
+                    x, n.astype(x.dtype), layer, 0), cache, new_cache)
     if cfg.post_block_norm:
         out = rmsnorm(params["post_ln1"], out, cfg.norm_eps)
     h = h + out
@@ -268,21 +277,18 @@ def apply_segment(cfg: ModelConfig, flags: RunFlags, seg: Segment, params, h,
             body = jax.checkpoint(period_body)
 
         def decode_body(carry, xs):
-            # the stacked cache rides in the carry and each layer's update
-            # is written back in place: as scan xs -> ys it would need a
-            # second full-size cache buffer for the outputs
+            # the stacked cache rides in the carry (as scan xs -> ys it
+            # would need a second full-size cache buffer for the outputs)
+            # and each block updates layer i of it in place: attention
+            # writes one row a slot, recurrent state is written back whole
             h_, aux_, c_all = carry
             p_stacked, i = xs
             c_all = list(c_all)
             for pos in range(seg.period):
-                c = jax.tree.map(lambda x: x[i], c_all[pos])
-                h_, nc, aux = apply_block(cfg, flags, sigs[pos],
-                                          p_stacked[pos], h_, positions, c,
-                                          mode)
+                h_, c_all[pos], aux = apply_block(
+                    cfg, flags, sigs[pos], p_stacked[pos], h_, positions,
+                    c_all[pos], mode, layer=i)
                 aux_ = aux_ + aux
-                c_all[pos] = jax.tree.map(
-                    lambda x, n: jax.lax.dynamic_update_index_in_dim(
-                        x, n.astype(x.dtype), i, 0), c_all[pos], nc)
             return (h_, aux_, tuple(c_all)), None
 
         p_xs = tuple(params["stack"])
