@@ -6,13 +6,14 @@
 
 In one process, for each seed: weights from the seed, the cell's traffic
 served through a fresh runtime of the cell for its lead-in and
-``--seconds`` (the cell's own load and sizes), and the sample that
-``run.py`` would compare; then, with the program's state freed, the
-widest reference-logit gap of the served tokens (the sound reading) and,
-for the control seeds, the gap of the tokens that the float8 reference
-puts first at the same positions (the control's reading). One JSON line per seed. The limit in
-the cell's traffic file is set between the largest sound reading and the
-smallest control reading. The benchmark's own runs never call this.
+``--seconds`` (the cell's own load and sizes, a replica cell through
+its router), and the sample that ``run.py`` would compare; then, with
+the program's state freed, the widest reference-logit gap of the served
+tokens (the sound reading) and, for the control seeds, the gap of the
+tokens that the float8 reference puts first at the same positions (the
+control's reading). One JSON line per seed. The limit in the cell's
+traffic file is set between the largest sound reading and the smallest
+control reading. The benchmark's own runs never call this.
 """
 from __future__ import annotations
 
@@ -30,25 +31,25 @@ def readings(config: dict, traffic: dict, seeds: list, controls: set,
     """One dict per seed: its sound reading, and the control's."""
     import jax
     from chipbench import check, generator, weights
-    from repro.serving import EngramRuntime
     dep = config["deployment"]
     cfg = run.model_config(config)
     rows = []
     for seed in seeds:
         params = weights.program_params(cfg, seed, device)
-        rt = EngramRuntime(cfg, params=params, pool=dep["pool"],
-                           max_batch=dep["max_batch"], max_len=dep["max_len"],
-                           prompt_bucket=dep["prompt_bucket"])
-        run.warm_up(rt, traffic, dep, cfg.vocab_size)
+        rt, runtimes = run.build(cfg, params, dep)
+        run.warm_up(runtimes, traffic, dep, cfg.vocab_size)
         reqs = generator.requests(traffic, cfg.vocab_size, seed, seconds)
         recs = [run.Rec(r.arrival_s, r.prompt, r.max_new) for r in reqs]
         t0 = time.perf_counter()
         t_open = t0 + float(traffic["lead_in_s"])
         run.serve(rt, jax, recs, t0, t_open, t_open + seconds, [])
-        finished = [(r.prompt, r.tokens) for r in recs if r.finished]
-        picked = check.sample(finished, seed, traffic["check"]["tokens"])
+        done = [r for r in recs if r.finished]
+        picked = check.sample([(r.prompt, r.tokens) for r in done], seed,
+                              traffic["check"]["tokens"],
+                              [runtimes.index(r.handle.runtime)
+                               for r in done])
         # the references run with the program's state freed
-        del rt, params
+        del rt, runtimes, params, done, recs
         gc.collect()
         row = {"seed": seed, "requests": len(picked),
                "tokens": sum(len(o) for _, o in picked),

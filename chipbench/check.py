@@ -2,7 +2,8 @@
 the plain float32 reference.
 
 A sample of the requests the run finished, drawn from the seed with the
-longest among them, is run through the reference once, each request as
+longest among them and, where several replicas served, at least one of
+each, is run through the reference once, each request as
 its prompt followed by its served tokens. At every served position the
 number read is the gap by which the served token's reference logit lies
 below the reference's best logit there; the run compares the widest gap
@@ -17,22 +18,40 @@ import importlib
 import numpy as np
 
 
-def sample(finished: list, seed: int, tokens: int) -> list:
-    """Finished ``(prompt, out)`` pairs: the one with the most served
-    tokens, then others in a seeded order until ``tokens`` are covered."""
+def sample_at(finished: list, seed: int, tokens: int,
+              replica: list = None) -> list:
+    """Indices into the finished ``(prompt, out)`` pairs: the one with the
+    most served tokens, then others in a seeded order until ``tokens`` are
+    covered. ``replica``: the replica that served each pair; the first of
+    each replica in that order then comes right after the longest, so that
+    every replica's tokens are compared."""
     if not finished:
         return []
     order = sorted(range(len(finished)), key=lambda i: -len(finished[i][1]))
     first, rest = order[0], order[1:]
     rng = np.random.default_rng(seed)
     rest = [rest[i] for i in rng.permutation(len(rest))]
+    lead = []
+    if replica is not None:
+        seen = {replica[first]}
+        for i in rest:
+            if replica[i] not in seen:
+                seen.add(replica[i])
+                lead.append(i)
+        rest = lead + [i for i in rest if i not in set(lead)]
     out, n = [], 0
-    for i in [first] + rest:
-        if n >= tokens:
+    for k, i in enumerate([first] + rest):
+        if n >= tokens and k > len(lead):
             break
-        out.append(finished[i])
+        out.append(i)
         n += len(finished[i][1])
     return out
+
+
+def sample(finished: list, seed: int, tokens: int,
+           replica: list = None) -> list:
+    """The pairs that ``sample_at`` picks."""
+    return [finished[i] for i in sample_at(finished, seed, tokens, replica)]
 
 
 def reference(config: dict, seed: int, precision: str = "float32"):

@@ -1,6 +1,7 @@
 """Peak device memory in use over the run (``peak_bytes_in_use`` of the
-device, read after the window), in GB. Warm-up runs the cell's largest
-programs, so this is what a deployment has to provision."""
+fullest of the cell's chips, read after the window), in GB. Warm-up runs
+the cell's largest programs, so this is what a deployment has to
+provision on each chip."""
 
 
 def read(ctx):

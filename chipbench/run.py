@@ -4,15 +4,17 @@
     python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s> \\
         --trace <0|1>
 
-Runs one cell of ``BENCHMARK.json`` on the chip it is started on: builds
-the cell's model (``chipbench/configs/<config>.json``) with weights made
-on the device from the seed, serves the cell's open-loop traffic
-(``chipbench/traffic/<cell>.json``) through one ``repro.serving.
-EngramRuntime`` for a lead-in and then a measured window of ``--seconds``,
-and checks what it served against the plain float32 reference
-(``chipbench/check.py``). ``--trace 0`` reports the cell's end-to-end
-metrics, ``--trace 1`` traces a few seconds of the window and reports its
-per-layer metrics (``chipbench/metrics/<metric>.py``).
+Runs one cell of ``BENCHMARK.json`` on the chips it is started on: builds
+the cell's model (``chipbench/configs/<config>.json``, through
+``chipbench/loader.py``) with weights made on the device from the seed,
+serves the cell's open-loop traffic (``chipbench/traffic/<cell>.json``)
+through one ``repro.serving.EngramRuntime``, or through a
+``repro.serving.Router`` over one replica per chip where the deployment
+states ``replicas``, for a lead-in and then a measured window of
+``--seconds``, and checks what it served against the plain float32
+reference (``chipbench/check.py``). ``--trace 0`` reports the cell's
+end-to-end metrics, ``--trace 1`` traces a few seconds of the window and
+reports its per-layer metrics (``chipbench/metrics/<metric>.py``).
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
@@ -56,7 +58,8 @@ def load_cell(name: str, root: Path = ROOT) -> tuple[dict, dict, dict]:
         raise SystemExit(f"unknown workload {name!r}; have {sorted(cells)}")
     cell = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
-    config = json.loads((root / configs[cell["config"]]["file"]).read_text())
+    from chipbench import loader
+    config = loader.read(configs[cell["config"]]["file"], root)
     traffic = json.loads(
         (root / "chipbench" / "traffic" / f"{cell['traffic']}.json").read_text())
     return cell, config, traffic
@@ -80,22 +83,32 @@ def reader(metric: str):
 
 
 def model_config(c: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import EngramConfig, ModelConfig, StoreConfig
-    e, dep = c["engram"], c["deployment"]
-    eng = EngramConfig(orders=tuple(e["orders"]), n_heads=e["n_heads"],
-                       emb_dim=e["emb_dim"], table_vocab=e["table_vocab"],
-                       layers=tuple(e["layers"]), strategy=e["strategy"],
-                       seed=e["seed"], pad_token=e["pad_token"],
-                       store=StoreConfig(cache_rows=dep["cache_rows"]))
-    d, H = c["hidden_size"], c["num_attention_heads"]
-    return ModelConfig(
-        name=c["name"], family="dense", n_layers=c["num_hidden_layers"],
-        d_model=d, vocab_size=c["vocab_size"], n_heads=H,
-        n_kv_heads=c["num_key_value_heads"], head_dim=d // H,
-        d_ff=c["intermediate_size"], rope_theta=float(c["rope_theta"]),
-        norm_eps=float(c["rms_norm_eps"]), dtype=c["torch_dtype"],
-        engram=eng)
+    """The program's ``ModelConfig`` for a configuration file: built by the
+    program's loader (``repro.configs.from_file``) where it has one, by
+    ``chipbench.loader`` otherwise, and checked against the file's sizes
+    before any weight is made."""
+    import repro.configs
+    from chipbench import loader
+    cfg = getattr(repro.configs, "from_file", loader.from_file)(c)
+    loader.check(c, cfg)
+    return cfg
+
+
+def build(cfg, params, dep: dict):
+    """The serving front the deployment states, and its runtimes: one
+    ``EngramRuntime``, or a ``Router`` over ``dep["replicas"]`` of them,
+    replica r on chip r, with the deployment's dispatch ``policy`` and
+    ``shared_cache``."""
+    from repro.serving import EngramRuntime, Router
+    kw = dict(params=params, pool=dep["pool"], max_batch=dep["max_batch"],
+              max_len=dep["max_len"], prompt_bucket=dep["prompt_bucket"])
+    n = dep.get("replicas", 1)
+    if n == 1:
+        rt = EngramRuntime(cfg, **kw)
+        return rt, [rt]
+    router = Router(cfg, replicas=n, policy=dep["policy"],
+                    shared_cache=dep["shared_cache"], **kw)
+    return router, list(router.replicas)
 
 
 def prompt_buckets(traffic: dict, bucket: int) -> list[int]:
@@ -124,6 +137,7 @@ class Rec:
     stamps: list = dataclasses.field(default_factory=list)
     tokens: list = dataclasses.field(default_factory=list)
     finished: bool = False
+    handle: object = None      # the program's RequestHandle
 
 
 @dataclasses.dataclass
@@ -143,7 +157,8 @@ class Context:
     requests: list             # Rec of every request submitted
     steps: list                # Step inside the window
     t_traffic: float           # wall time the traffic started
-    counters: dict             # EngineStats counters over the window
+    counters: dict             # EngineStats counters over the window,
+                               # summed over replicas
     setup_s: float
     peak_bytes: int
     trace: object = None       # chipbench.trace.Reduced (--trace 1)
@@ -164,27 +179,33 @@ class CompileCounter:
             self.traces += 1
 
 
-def _counters(stats) -> dict:
-    return {k: v for k, v in dataclasses.asdict(stats).items()
-            if isinstance(v, (int, float))}
+def _counters(runtimes) -> dict:
+    out = {}
+    for rt in runtimes:
+        for k, v in dataclasses.asdict(rt.stats).items():
+            if isinstance(v, (int, float)):
+                out[k] = out.get(k, 0) + v
+    return out
 
 
 # --------------------------------------------------------------- serving
 
-def warm_up(rt, traffic: dict, dep: dict, vocab: int) -> int:
+def warm_up(runtimes, traffic: dict, dep: dict, vocab: int) -> int:
     """Run every admission shape the mix can produce (each prompt bucket
-    by each padded group size) and the decode, key and sync programs once.
-    Returns the number of shapes."""
+    by each padded group size) and the decode, key and sync programs once
+    on every runtime (each replica's chip compiles its own). Returns the
+    number of shapes."""
     shapes = [(S, n) for S in prompt_buckets(traffic, dep["prompt_bucket"])
               for n in group_sizes(dep["max_batch"])]
-    # the first wave takes the engine's freshly made state and tokens,
-    # which jit keys apart from the state a program returned: served
-    # waves all see the latter, so the first shape runs twice
-    for S, n in [shapes[0]] + shapes:
-        for r in range(n):
-            rt.submit([(7919 * i + 104729 * r + S) % vocab
-                       for i in range(S)], max_new=2)
-        rt.drain()
+    for rt in runtimes:
+        # the first wave takes the engine's freshly made state and tokens,
+        # which jit keys apart from the state a program returned: served
+        # waves all see the latter, so the first shape runs twice
+        for S, n in [shapes[0]] + shapes:
+            for r in range(n):
+                rt.submit([(7919 * i + 104729 * r + S) % vocab
+                           for i in range(S)], max_new=2)
+            rt.drain()
     return len(shapes)
 
 
@@ -210,7 +231,8 @@ def serve(rt, jax, recs: list, t_traffic: float, t_open: float,
                 while i < n and t_traffic + recs[i].due <= now:
                     r = recs[i]
                     r.submitted = time.perf_counter()
-                    by_rid[rt.submit(list(r.prompt), r.max_new).rid] = r
+                    r.handle = rt.submit(list(r.prompt), r.max_new)
+                    by_rid[r.handle.rid] = r
                     i += 1
         if rt.busy:
             k = len(steps)
@@ -238,24 +260,24 @@ def serve(rt, jax, recs: list, t_traffic: float, t_open: float,
 
 def run_cell(config: dict, traffic: dict, *, seed: int,
              seconds: float, trace: bool, peaks: dict, device,
-             e2e: list, per_layer: list, t_process: float = _T_PROCESS
-             ) -> dict:
-    """One run of one cell on ``device``: the result line's dict."""
+             e2e: list, per_layer: list, t_process: float = _T_PROCESS,
+             chips: int = 1) -> dict:
+    """One run of one cell on the first ``chips`` devices, ``device``
+    first: the result line's dict."""
     import jax
 
     from chipbench import check, generator, weights
-    from repro.serving import EngramRuntime
     dep = config["deployment"]
     cfg = model_config(config)
+    devices = [device] + [d for d in jax.devices() if d != device][
+        :chips - 1]
     clock = CompileCounter(jax)
     t0 = time.perf_counter()
     params = jax.block_until_ready(weights.program_params(cfg, seed, device))
     t1 = time.perf_counter()
-    rt = EngramRuntime(cfg, params=params, pool=dep["pool"],
-                       max_batch=dep["max_batch"], max_len=dep["max_len"],
-                       prompt_bucket=dep["prompt_bucket"])
+    rt, runtimes = build(cfg, params, dep)
     t2 = time.perf_counter()
-    n_shapes = warm_up(rt, traffic, dep, cfg.vocab_size)
+    n_shapes = warm_up(runtimes, traffic, dep, cfg.vocab_size)
     print(f"chipbench weights_s={t1 - t0!r} engine_s={t2 - t1!r} "
           f"warm_up_s={time.perf_counter() - t2!r} "
           f"compiles_in_setup={clock.compiles}", flush=True)
@@ -272,7 +294,7 @@ def run_cell(config: dict, traffic: dict, *, seed: int,
 
     def on_open():
         marks["setup_s"] = t_open - t_process
-        marks["counters"] = _counters(rt.stats)
+        marks["counters"] = _counters(runtimes)
         marks["compiles"] = (clock.compiles, clock.traces)
         marks["step0"] = len(steps)
         if trace:
@@ -291,16 +313,20 @@ def run_cell(config: dict, traffic: dict, *, seed: int,
     compiles = clock.compiles - marks["compiles"][0]
     traces = clock.traces - marks["compiles"][1]
     counters = {k: v - marks["counters"].get(k, 0)
-                for k, v in _counters(rt.stats).items()}
-    stats = device.memory_stats() or {}
-    peak = int(stats.get("peak_bytes_in_use", 0))
+                for k, v in _counters(runtimes).items()}
+    peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devices)
     if trace:
         from chipbench import trace as tr
         found = sorted(tmp.rglob("*.xplane.pb"))
         if not found:
             raise RuntimeError("the profiler wrote no trace")
-        red = tr.load(found[-1])
+        red = tr.load(found[-1], chips)
         shutil.rmtree(tmp, ignore_errors=True)
+        if chips > 1:
+            print("chipbench idle_share_per_chip="
+                  f"{[100.0 * (1.0 - b / red.window_ns) for b in red.busy_ns_per_chip()]!r}",
+                  flush=True)
     late = sorted(r.submitted - (t_traffic + r.due) for r in recs
                   if r.submitted)
     print(f"chipbench shapes_warmed={n_shapes} compiles_in_window={compiles} "
@@ -322,13 +348,19 @@ def run_cell(config: dict, traffic: dict, *, seed: int,
         if v is not None:
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     due = [r for r in recs if t_open <= t_traffic + r.due < t_close]
-    finished = [(r.prompt, r.tokens) for r in recs if r.finished]
-    malformed = sum(1 for r in recs if r.finished and (
-        len(r.tokens) != r.max_new
-        or not all(0 <= t < cfg.vocab_size for t in r.tokens)))
-    picked = check.sample(finished, seed, traffic["check"]["tokens"])
+    done = [r for r in recs if r.finished]
+    finished = [(r.prompt, r.tokens) for r in done]
+    replica = [runtimes.index(r.handle.runtime) for r in done]
+    malformed = sum(1 for r in done if len(r.tokens) != r.max_new
+                    or not all(0 <= t < cfg.vocab_size for t in r.tokens))
+    picked_at = check.sample_at(finished, seed, traffic["check"]["tokens"],
+                                replica)
+    picked = [finished[i] for i in picked_at]
+    not_compared = len(runtimes) - len({replica[i] for i in picked_at})
     # the reference runs with the program's state freed
-    del rt, params
+    del rt, runtimes, params, done
+    for r in recs:
+        r.handle = None
     gc.collect()
     t_ref = time.perf_counter()
     gap = check.widest_gap(config, seed, picked, dep["max_len"]) \
@@ -340,14 +372,18 @@ def run_cell(config: dict, traffic: dict, *, seed: int,
           flush=True)
     checks = {"max_logit_gap": {"value": gap, "limit": limit},
               "malformed_outputs": {"value": malformed, "limit": 0}}
-    correct = gap is not None and gap <= limit and malformed == 0
+    if dep.get("replicas", 1) > 1:
+        checks["replicas_not_compared"] = {"value": not_compared, "limit": 0}
+    correct = gap is not None and gap <= limit and malformed == 0 \
+        and not_compared == 0
     dev = {"platform": device.platform, "kind": device.device_kind,
            "count": len(jax.devices()), "memory_peak_bytes": peak}
     out = {"correct": correct, "attempted": len(due), "failed": 0,
            "metrics": metrics, "device": dev}
     if red is not None:
         from chipbench import trace as tr
-        dev["busy_s"] = red.busy_ns() * 1e-9
+        busy = red.busy_ns_per_chip()
+        dev["busy_s"] = sum(busy) / len(busy) * 1e-9
         dev["window_s"] = red.window_ns * 1e-9
         out["breakdown"] = tr.breakdown(red)
     out["check"] = checks
@@ -386,7 +422,7 @@ def main(argv=None) -> int:
           f"devices={len(devices)}x{device.device_kind}", flush=True)
     out = run_cell(config, traffic, seed=args.seed,
                    seconds=args.seconds, trace=bool(args.trace), peaks=peaks,
-                   device=device,
+                   device=device, chips=cell["chips"],
                    e2e=metric_specs(args.workload, "end_to_end"),
                    per_layer=metric_specs(args.workload, "per_layer"))
     for k, v in out["check"].items():

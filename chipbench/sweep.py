@@ -4,9 +4,10 @@
     python3 chipbench/sweep.py --workload <cell> --seed <n> \\
         --seconds <s> --rates 1,2,4,...
 
-Builds the cell once, then serves its traffic mix at each rate for a
-lead-in and ``--seconds`` and prints, per rate, what was due, finished
-and still waiting at the close, and the tail of the time to first token.
+Builds the cell once (a replica cell as its router over one replica per
+chip), then serves its traffic mix at each rate for a lead-in and
+``--seconds`` and prints, per rate, what was due, finished and still
+waiting at the close, and the tail of the time to first token.
 A rate whose backlog at the close stays within the slots is sustained;
 the cell's mix then offers about four fifths of the highest such rate.
 Run on the chip, once, when a cell is defined; the benchmark's own runs
@@ -35,7 +36,6 @@ def main(argv=None) -> int:
     import jax
     from chipbench import generator, weights
     from repro.launch.cache import enable_compile_cache
-    from repro.serving import EngramRuntime
     if jax.devices()[0].platform != "tpu":
         print("sweep: needs a TPU", file=sys.stderr)
         return 2
@@ -44,10 +44,8 @@ def main(argv=None) -> int:
     dep = config["deployment"]
     cfg = run.model_config(config)
     params = weights.program_params(cfg, args.seed, jax.devices()[0])
-    rt = EngramRuntime(cfg, params=params, pool=dep["pool"],
-                       max_batch=dep["max_batch"], max_len=dep["max_len"],
-                       prompt_bucket=dep["prompt_bucket"])
-    run.warm_up(rt, traffic, dep, cfg.vocab_size)
+    rt, runtimes = run.build(cfg, params, dep)
+    run.warm_up(runtimes, traffic, dep, cfg.vocab_size)
     for rate in [float(r) for r in args.rates.split(",")]:
         mix = dict(traffic, arrivals=dict(traffic["arrivals"],
                                           rate_per_s=rate))

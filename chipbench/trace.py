@@ -3,10 +3,12 @@ metric readers share.
 
 The traced window is the host span ``chipbench.window`` that the harness
 opens around the traced part of the run. Device work is the events of the
-``XLA Ops`` line of the first TPU plane; programs are the events of its
-``XLA Modules`` line, named ``jit_<function>`` after the jitted function
-(``jit__decode_ext_fn``, ``jit__admit_wave_fn`` ...). Host spans are the
-harness's own ``chipbench.*`` annotations.
+``XLA Ops`` line of a TPU plane; programs are the events of its ``XLA
+Modules`` line, named ``jit_<function>`` after the jitted function
+(``jit_decode_step``, ``jit__admit_wave_fn`` ...). A cell on ``chips``
+chips reduces the first ``chips`` TPU planes by name, one per chip;
+``ops`` and ``programs`` are the first chip's, which is all a one-chip
+cell has. Host spans are the harness's own ``chipbench.*`` annotations.
 """
 from __future__ import annotations
 
@@ -28,15 +30,24 @@ class Event:
 
 
 @dataclasses.dataclass
-class Reduced:
-    window: tuple             # (start, end) ns of the traced window
+class Chip:
     ops: list                 # device op events inside the window
     programs: list            # device program events inside the window
+
+
+@dataclasses.dataclass
+class Reduced:
+    window: tuple             # (start, end) ns of the traced window
+    ops: list                 # the first chip's op events inside the window
+    programs: list            # the first chip's program events
     spans: list               # host chipbench.* spans inside the window
+    chips: list = None        # Chip of every chip of the cell, first first
 
     def __post_init__(self):
         self.ops = sorted(self.ops, key=lambda e: e.start)
         self._starts = [e.start for e in self.ops]
+        if self.chips is None:
+            self.chips = [Chip(self.ops, self.programs)]
 
     def ops_between(self, lo: float, hi: float) -> list:
         """Device op events that start in [lo, hi)."""
@@ -49,6 +60,10 @@ class Reduced:
 
     def busy_ns(self) -> float:
         return union_length([(e.start, e.end) for e in self.ops])
+
+    def busy_ns_per_chip(self) -> list:
+        return [union_length([(e.start, e.end) for e in c.ops])
+                for c in self.chips]
 
     def spans_named(self, name: str) -> list:
         return [s for s in self.spans if s.name == name]
@@ -98,15 +113,25 @@ def _events(line, lo=None, hi=None) -> list:
     return out
 
 
-def _device_plane(profile):
+def _device_planes(profile, chips: int) -> list:
     planes = [p for p in profile.planes if p.name.startswith("/device:TPU:")]
-    if not planes:
-        raise ValueError("the trace holds no TPU plane")
-    return sorted(planes, key=lambda p: p.name)[0]
+    if len(planes) < chips:
+        raise ValueError(f"the trace holds {len(planes)} TPU planes, the "
+                         f"cell asks for {chips}")
+    return sorted(planes, key=lambda p: p.name)[:chips]
 
 
-def reduce(profile) -> Reduced:
-    """``profile``: a ``jax.profiler.ProfileData``."""
+def _chip(plane, lo, hi) -> Chip:
+    lines = {ln.name: ln for ln in plane.lines}
+    if "XLA Ops" not in lines or "XLA Modules" not in lines:
+        raise ValueError(f"TPU plane lines {sorted(lines)}")
+    return Chip(_events(lines["XLA Ops"], lo, hi),
+                _events(lines["XLA Modules"], lo, hi))
+
+
+def reduce(profile, chips: int = 1) -> Reduced:
+    """``profile``: a ``jax.profiler.ProfileData``; ``chips``: the TPU
+    planes of the cell."""
     spans = []
     for plane in profile.planes:
         if not plane.name.startswith("/host:"):
@@ -118,40 +143,43 @@ def reduce(profile) -> Reduced:
     if len(windows) != 1:
         raise ValueError(f"{len(windows)} {WINDOW} spans in the trace")
     lo, hi = windows[0].start, windows[0].end
-    dev = _device_plane(profile)
-    lines = {ln.name: ln for ln in dev.lines}
-    if "XLA Ops" not in lines or "XLA Modules" not in lines:
-        raise ValueError(f"TPU plane lines {sorted(lines)}")
+    per_chip = [_chip(p, lo, hi) for p in _device_planes(profile, chips)]
     return Reduced(
-        window=(lo, hi),
-        ops=_events(lines["XLA Ops"], lo, hi),
-        programs=_events(lines["XLA Modules"], lo, hi),
+        window=(lo, hi), ops=per_chip[0].ops, programs=per_chip[0].programs,
         spans=sorted([s for s in spans if s.name != WINDOW
                       and s.end > lo and s.start < hi],
-                     key=lambda s: s.start))
+                     key=lambda s: s.start),
+        chips=per_chip)
 
 
-def load(path) -> Reduced:
+def load(path, chips: int = 1) -> Reduced:
     """Reduce an ``.xplane.pb`` file (gzipped or not)."""
     from jax.profiler import ProfileData
     data = Path(path).read_bytes()
     if data[:2] == b"\x1f\x8b":
         data = gzip.decompress(data)
-    return reduce(ProfileData.from_serialized_xspace(data))
+    return reduce(ProfileData.from_serialized_xspace(data), chips)
 
 
 def breakdown(red: Reduced, top: int = 10) -> dict:
-    """The device programs that took most time, and the longest idle gaps
-    labelled with the host span they fell in."""
+    """The device programs that took most time (summed over the chips),
+    and the longest idle gaps labelled with the host span they fell in
+    (and, on several chips, the chip)."""
     per = {}
-    for p in red.programs:
-        per[p.name] = per.get(p.name, 0.0) + (p.end - p.start)
+    for chip in red.chips:
+        for p in chip.programs:
+            per[p.name] = per.get(p.name, 0.0) + (p.end - p.start)
     device_ops = sorted(per.items(), key=lambda kv: -kv[1])[:top]
-    gaps = idle_gaps([(e.start, e.end) for e in red.ops], *red.window)
-    gaps = sorted(gaps, key=lambda g: g[0] - g[1])[:top]
+    gaps = []
+    for k, chip in enumerate(red.chips):
+        suffix = f" TPU:{k}" if len(red.chips) > 1 else ""
+        gaps += [(g, suffix) for g in
+                 idle_gaps([(e.start, e.end) for e in chip.ops],
+                           *red.window)]
+    gaps = sorted(gaps, key=lambda g: g[0][0] - g[0][1])[:top]
     return {"device_ops": [[n, t * 1e-9] for n, t in device_ops],
-            "idle_gaps": [[host_label(red, g), (g[1] - g[0]) * 1e-9]
-                          for g in gaps]}
+            "idle_gaps": [[host_label(red, g) + suffix, (g[1] - g[0]) * 1e-9]
+                          for g, suffix in gaps]}
 
 
 def host_label(red: Reduced, gap) -> str:

@@ -4,14 +4,18 @@ Every weight is a pure function of (seed, the weight's logical name, its
 flat index), so the program's parameters can be made on the device in one
 jitted call, and the plain reference can make the same values again, one
 layer or one set of table rows at a time, without taking anything the
-program made. Values are uniform with the program's own init scale
-(``1/sqrt(fan_in)``, 1.0 for the embedding, norm scales 1), rounded once
-to the served dtype.
+program made. Values are uniform with scale ``1/sqrt(fan_in)`` (1.0 for
+the embedding, norm scales 1), rounded once to the served dtype. The
+fan-in is a matrix's first axis, and for a leaf stacked over experts
+(``w_gu`` ``(E, d, 2F)``, ``w_down`` ``(E, F, d)``) that of one expert's
+matrix.
 
 Logical names: ``embed.w``, ``head.w``, ``final_norm.scale``,
 ``layer{i}.{ln1|ln2}.scale``, ``layer{i}.mixer.{wq|wk|wv|wo}``,
-``layer{i}.ffn.{gate|up|down}``, ``engram{j}.{tables|proj|gate}``,
-``engram{j}.norm.scale``.
+``layer{i}.mixer.{wdq|wuq|wdkv|wuk|wuv}`` (MLA),
+``layer{i}.ffn.{gate|up|down}``, ``layer{i}.ffn.{router|w_gu|w_down}``,
+``layer{i}.ffn.shared.{gate|up|down}`` (expert layers),
+``engram{j}.{tables|proj|gate}``, ``engram{j}.norm.scale``.
 """
 from __future__ import annotations
 
@@ -24,6 +28,8 @@ import numpy as np
 
 _C1, _C2 = np.uint32(0x85EBCA6B), np.uint32(0xC2B2AE35)
 _GOLD = np.uint32(0x9E3779B9)
+# leaves stacked over experts on their first axis
+EXPERT_STACKS = (".ffn.w_gu", ".ffn.w_down")
 
 
 def _fmix32(x):
@@ -68,7 +74,8 @@ def std_for(name: str, shape) -> float | None:
         return None
     if name == "embed.w":
         return 1.0
-    return 1.0 / math.sqrt(max(int(shape[0]), 1))
+    fan_in = shape[1] if name.endswith(EXPERT_STACKS) else shape[0]
+    return 1.0 / math.sqrt(max(int(fan_in), 1))
 
 
 def _flat_iota(shape):
