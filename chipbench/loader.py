@@ -17,6 +17,29 @@ the program has one, and this mapping otherwise.
 ``check`` holds the program's config to the file on every size that
 ``chipbench/counters.py`` reads, from the shapes of
 ``abstract_params(cfg)``: nothing is made, so it runs before any weight.
+
+Expert routing and YaRN (DeepSeek-V3) are mapped here and modelled by the
+reference (``references/mla_moe_engram.py``); ``from_file`` refuses them
+by name, since the program it builds routes by softmax top-k and rotates
+with one theta. A program's own loader that models them fills this
+contract, which ``check`` reads:
+
+- ``cfg.moe.scoring_func`` (``"softmax"`` | ``"sigmoid"``),
+  ``cfg.moe.topk_method`` (``"greedy"`` | ``"noaux_tc"``),
+  ``cfg.moe.n_group``, ``cfg.moe.topk_group``, ``cfg.moe.norm_topk_prob``
+  and ``cfg.moe.router_scale`` (``routed_scaling_factor``), each read as
+  today's behaviour (``ROUTING``) where the attribute is missing;
+- ``cfg.rope_scaling``: None, or the file's ``rope_scaling`` as a mapping
+  or as (key, value) pairs;
+- under ``noaux_tc`` each expert layer holds the selection bias
+  (``e_score_correction_bias``) as the leaf ``router_bias`` beside
+  ``router``, shape (routed,); both leaves are float32;
+- a chip of ``deployment.expert_parallel`` holds the logical experts
+  ``[0, n_routed_experts)`` of the ``w_gu`` / ``w_down`` stacks and routes
+  over all ``n_routed_experts * expert_parallel``.
+
+Routing facts are compared for files with expert layers, ``rope_scaling``
+for every file, each unless the file lists the key as a departure.
 """
 from __future__ import annotations
 
@@ -42,14 +65,23 @@ MAPPED = {
     "qk_rope_head_dim", "v_head_dim", "n_routed_experts",
     "num_experts_per_tok", "n_shared_experts", "moe_intermediate_size",
     "first_k_dense_replace", "moe_layer_freq", "routed_scaling_factor",
+    "scoring_func", "topk_method", "n_group", "topk_group",
+    "norm_topk_prob", "rope_scaling",
 }
-# keys the program models at one value only: softmax top-k routing with
-# the top-k weights renormalized, SiLU gates, no projection biases
+# the values of mapped keys that are modelled; any other is unmodelled
+MAPPED_VALUES = {"scoring_func": ("softmax", "sigmoid"),
+                 "topk_method": ("greedy", "noaux_tc")}
+# keys modelled at one value only: SiLU gates, no projection biases
 ONLY = {
-    "hidden_act": "silu", "scoring_func": "softmax", "topk_method": "greedy",
-    "norm_topk_prob": True, "attention_bias": False, "mlp_bias": False,
-    "n_group": 1, "topk_group": 1, "ep_size": 1,
+    "hidden_act": "silu", "attention_bias": False, "mlp_bias": False,
+    "ep_size": 1,
 }
+# the routing of a file that states none, and of a program config that
+# has no attribute for it: softmax top-k with the top-k weights
+# renormalized, in one group
+ROUTING = {"scoring_func": "softmax", "topk_method": "greedy", "n_group": 1,
+           "topk_group": 1, "norm_topk_prob": True,
+           "routed_scaling_factor": 1.0}
 
 
 def read(path, root: Path) -> dict:
@@ -71,17 +103,63 @@ def departures(c: dict) -> set:
     return set(c.get("reduced", {})) | set(c.get("assumed", {}))
 
 
+def is_yarn(rope_scaling) -> bool:
+    return isinstance(rope_scaling, dict) and \
+        rope_scaling.get("type") == "yarn"
+
+
+def _modelled(k, v) -> bool:
+    if k == "rope_scaling":
+        return v is None or is_yarn(v)
+    return v in MAPPED_VALUES.get(k, (v,))
+
+
 def unmodelled(c: dict) -> list:
     """Keys of ``c`` that change the served model, that the mapping below
     does not model, and that the file does not list as departures."""
     listed = departures(c)
     bad = []
     for k, v in c.items():
-        if k in NOT_SERVED or k in MAPPED or k in listed:
+        if k in NOT_SERVED or k in listed:
+            continue
+        if k in MAPPED and _modelled(k, v):
             continue
         if k in ONLY and v == ONLY[k]:
             continue
         bad.append(k)
+    return bad
+
+
+def routing(c: dict) -> dict:
+    """The file's routing: each key of ``ROUTING`` as the file states it,
+    or its default where the file states none (or null) or lists the key
+    as a departure."""
+    listed = departures(c)
+    r = {k: v if c.get(k) is None or k in listed else c[k]
+         for k, v in ROUTING.items()}
+    r["routed_scaling_factor"] = float(r["routed_scaling_factor"])
+    return r
+
+
+def rope_scaling(c: dict):
+    """The file's ``rope_scaling``, None where it states none or lists it
+    as a departure."""
+    return None if "rope_scaling" in departures(c) \
+        else c.get("rope_scaling") or None
+
+
+def _not_held(c: dict) -> list:
+    """What the file states that ``from_file``'s program cannot hold: an
+    expert share, routing other than ``ROUTING``, RoPE scaling."""
+    bad = [f"{k}={c[k]!r}" for k, v in routing(c).items()
+           if k != "routed_scaling_factor" and v != ROUTING[k]]
+    if rope_scaling(c) is not None:
+        bad.append(f"rope_scaling={c['rope_scaling']!r}")
+    held, routed = counters.expert_share(c)
+    if held != routed:
+        bad.append(f"deployment.expert_parallel="
+                   f"{c['deployment']['expert_parallel']} ({held} experts "
+                   f"held of {routed} routed)")
     return bad
 
 
@@ -95,13 +173,13 @@ def from_file(c: dict):
             f"{c.get('name')}: keys not modelled: "
             + ", ".join(f"{k}={c[k]!r}" for k in bad)
             + "; list each under reduced or assumed as a departure")
-    held, routed = counters.expert_share(c)
-    if held != routed:
+    bad = _not_held(c)
+    if bad:
         raise ValueError(
-            f"{c.get('name')}: n_routed_experts {held} held of {routed} "
-            f"routed (deployment.expert_parallel "
-            f"{c['deployment'].get('expert_parallel')}): the program's "
-            f"expert layer holds every expert it routes over")
+            f"{c.get('name')}: the program's config cannot hold "
+            + ", ".join(bad) + ": its expert layer holds every expert it "
+            "routes over by softmax top-k, and its RoPE takes one theta")
+    held, _ = counters.expert_share(c)
     e, dep = c["engram"], c["deployment"]
     eng = EngramConfig(orders=tuple(e["orders"]), n_heads=e["n_heads"],
                        emb_dim=e["emb_dim"], table_vocab=e["table_vocab"],
@@ -174,6 +252,8 @@ def expected_shapes(c: dict) -> dict:
             out.update({f + "router": (d, routed),
                         f + "w_gu": (held, d, 2 * Fe),
                         f + "w_down": (held, Fe, d)})
+            if routing(c)["topk_method"] == "noaux_tc":
+                out[f + "router_bias"] = (routed,)
             ns = c.get("n_shared_experts") or 0
             if ns:
                 out.update({f + "shared.gate": (d, ns * Fe),
@@ -194,8 +274,9 @@ def expected_shapes(c: dict) -> dict:
     return out
 
 
-def program_shapes(cfg) -> dict:
-    """Logical weight name -> shape in the program's parameter tree."""
+def program_leaves(cfg) -> dict:
+    """Logical weight name -> (shape, dtype name) in the program's
+    parameter tree."""
     import jax
     from chipbench.weights import _layer_names, _path_parts
     from repro.models.model import abstract_params
@@ -207,17 +288,46 @@ def program_shapes(cfg) -> dict:
                                        else leaf.shape))
         for n in names:
             if not n.endswith(".scale"):
-                out[n] = shape
+                out[n] = shape, str(leaf.dtype)
     return out
+
+
+def _pairs(x):
+    """A ``rope_scaling`` (mapping or (key, value) pairs) as sorted pairs,
+    numbers as floats; None for none."""
+    if not x:
+        return None
+    items = x.items() if hasattr(x, "items") else x
+    return tuple(sorted(
+        (k, float(v) if isinstance(v, (int, float))
+         and not isinstance(v, bool) else v) for k, v in items))
+
+
+def program_routing(cfg) -> dict | None:
+    """The program config's routing by ``ROUTING``'s keys (None without
+    expert layers), today's behaviour where an attribute is missing."""
+    mo = cfg.moe
+    if mo is None:
+        return None
+    r = {k: getattr(mo, k, v) for k, v in ROUTING.items()
+         if k != "routed_scaling_factor"}
+    r["routed_scaling_factor"] = float(mo.router_scale)
+    return r
 
 
 def mismatches(c: dict, cfg) -> list:
     """Where the program's config ``cfg`` departs from file ``c``: one
-    line per weight whose shape differs, is missing or is extra, and per
-    size that the shapes do not show apart (heads and head dims, latent
-    dims, experts per token, shared experts, Engram layers)."""
-    want, got = expected_shapes(c), program_shapes(cfg)
-    bad = []
+    line per weight whose shape differs, is missing or is extra, per
+    router leaf not in float32, and per size or fact that the shapes do
+    not show apart (heads and head dims, latent dims, experts per token,
+    shared experts, routing, RoPE scaling, Engram layers)."""
+    leaves = program_leaves(cfg)
+    want = expected_shapes(c)
+    got = {n: sh for n, (sh, _) in leaves.items()}
+    bad = [f"{n}: file float32, program {dt}" for n, (_, dt) in
+           sorted(leaves.items())
+           if n.endswith((".ffn.router", ".ffn.router_bias"))
+           and dt != "float32"]
     for n in sorted(set(want) | set(got)):
         w, g = want.get(n), got.get(n)
         if n.endswith(".tables") and w and g:
@@ -249,12 +359,20 @@ def mismatches(c: dict, cfg) -> list:
     experts = counters.expert_layers(c)
     facts.append(("expert layers", [i for i, t in enumerate(cfg.ffn_types)
                                     if t == "moe"], experts))
+    listed = departures(c)
     if experts:
         mo = cfg.moe
         facts += [("num_experts_per_tok", mo and mo.top_k,
                    c["num_experts_per_tok"]),
                   ("n_shared_experts", mo and mo.n_shared,
                    c.get("n_shared_experts") or 0)]
+        got_r, want_r = program_routing(cfg), routing(c)
+        facts += [(k, got_r and got_r[k], want_r[k]) for k in ROUTING
+                  if k not in listed]
+    if "rope_scaling" not in listed:
+        facts.append(("rope_scaling",
+                      _pairs(getattr(cfg, "rope_scaling", None)),
+                      _pairs(c.get("rope_scaling"))))
     e, fe = cfg.engram, c["engram"]
     facts += [("engram layers", cfg.engram_layers(),
                tuple(counters.engram_layers(c))),

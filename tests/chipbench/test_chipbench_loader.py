@@ -50,6 +50,20 @@ def with_share(c, expert_parallel):
     return c
 
 
+# DeepSeek-V3's published routing, YaRN and MTP key at MLA_MOE's sizes,
+# its eight experts held of sixteen routed (8 groups of 2, top 4 groups)
+V3_ROPE = {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
+           "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
+           "type": "yarn"}
+V3_KEYED = with_share(dict(
+    MLA_MOE, name="deepseek-v3-keyed-reduced", scoring_func="sigmoid",
+    topk_method="noaux_tc", n_group=8, topk_group=4, norm_topk_prob=True,
+    routed_scaling_factor=2.5, rope_scaling=V3_ROPE,
+    num_nextn_predict_layers=0,
+    reduced={"num_nextn_predict_layers": [0, 1, "MTP is not served"],
+             "n_routed_experts": [8, 16, "held here of 16 routed"]}), 2)
+
+
 def before(c: dict):
     """The mapping the harness used before the loader (dense files)."""
     from repro.configs.base import EngramConfig, ModelConfig, StoreConfig
@@ -138,16 +152,23 @@ def test_check_refuses_a_program_config_that_drops_a_size(fault, where):
 
 def test_expert_share_maps_and_the_program_cannot_hold_it():
     """Eight experts held of sixteen routed: the file's sizes and counts
-    are read, and the program's config, whose router is as wide as the
-    experts it holds, is refused."""
+    are read, and the fallback loader's config, whose router is as wide
+    as the experts it holds, is refused by it and by the check."""
     shared = with_share(MLA_MOE, 2)
     assert counters.expert_share(shared) == (8, 16)
     assert loader.expected_shapes(shared)["layer1.ffn.router"] == (64, 16)
     assert loader.expected_shapes(shared)["layer1.ffn.w_gu"] == (8, 64, 64)
-    bad = loader.mismatches(shared, run.model_config(MLA_MOE))
+    today = loader.from_file(MLA_MOE)
+    bad = loader.mismatches(shared, today)
     assert any(line.startswith("layer1.ffn.router") for line in bad), bad
     with pytest.raises(ValueError, match="expert_parallel"):
-        run.model_config(shared)
+        loader.from_file(shared)
+    with pytest.raises(ValueError, match="layer1.ffn.router"):
+        loader.check(shared, today)
+
+
+# keys whose value the check compares with the program's config
+CHECKED = {"rope_scaling", "scoring_func", "topk_method", "n_group"}
 
 
 @pytest.mark.parametrize("key,value", [
@@ -156,11 +177,69 @@ def test_expert_share_maps_and_the_program_cannot_hold_it():
     ("scoring_func", "sigmoid"), ("topk_method", "noaux_tc"),
     ("n_group", 8)])
 def test_unmodelled_key_is_refused_unless_listed(key, value):
+    """The fallback loader refuses a key its program cannot hold, by
+    name, and the check refuses today's program for a compared key; a
+    key listed as a departure is neither built nor compared."""
     c = dict(MLA_MOE, **{key: value})
     with pytest.raises(ValueError, match=key):
-        run.model_config(c)
+        loader.from_file(c)
+    if key in CHECKED:
+        with pytest.raises(ValueError, match=key):
+            loader.check(c, loader.from_file(MLA_MOE))
     c["assumed"] = {key: "a departure: not modelled by the program"}
-    assert run.model_config(c) == run.model_config(MLA_MOE)
+    assert loader.from_file(c) == loader.from_file(MLA_MOE)
+    loader.check(c, loader.from_file(c))
+
+
+def test_v3_keyed_file_is_modelled_and_today_s_program_is_refused():
+    """V3's routing, YaRN and share are mapped; the check against today's
+    program names each fact, the router's width and its bias; the
+    fallback loader refuses the file, naming what it cannot hold."""
+    assert loader.unmodelled(V3_KEYED) == []
+    want = loader.expected_shapes(V3_KEYED)
+    assert want["layer1.ffn.router_bias"] == (16,)
+    assert want["layer1.ffn.router"] == (64, 16)
+    assert "layer0.ffn.router_bias" not in want        # the dense layer
+    assert "layer1.ffn.router_bias" not in loader.expected_shapes(MLA_MOE)
+    bad = loader.mismatches(V3_KEYED, loader.from_file(MLA_MOE))
+    for fact in ("scoring_func", "topk_method", "n_group", "topk_group",
+                 "routed_scaling_factor", "rope_scaling",
+                 "layer1.ffn.router_bias", "layer1.ffn.router:"):
+        assert any(line.startswith(fact) for line in bad), (fact, bad)
+    assert not any(line.startswith("norm_topk_prob") for line in bad)
+    with pytest.raises(ValueError) as e:
+        loader.from_file(V3_KEYED)
+    for key in ("scoring_func", "topk_method", "n_group", "topk_group",
+                "rope_scaling", "expert_parallel"):
+        assert key in str(e.value), key
+
+
+def test_check_reads_the_routing_contract_from_the_program_config(
+        monkeypatch):
+    """A program config that carries V3's routing attributes, its YaRN and
+    router bias leaves of the file's width passes; it is refused for a
+    routing fact that differs and for a bias not in float32."""
+    routing = dict(scoring_func="sigmoid", topk_method="noaux_tc",
+                   n_group=4, topk_group=2, norm_topk_prob=True)
+    cfg = loader.from_file(MLA_MOE)
+    moe = dataclasses.replace(cfg.moe, router_scale=2.5)
+    prog = dataclasses.replace(cfg)
+    for k, v in routing.items():         # the attributes the contract names
+        object.__setattr__(moe, k, v)
+    object.__setattr__(prog, "moe", moe)
+    object.__setattr__(prog, "rope_scaling", tuple(V3_ROPE.items()))
+    leaves = dict(loader.program_leaves(cfg))
+    leaves.update({f"layer{i}.ffn.router_bias": ((8,), "float32")
+                   for i in (1, 2, 3)})
+    monkeypatch.setattr(loader, "program_leaves", lambda _: leaves)
+    c = dict(MLA_MOE, routed_scaling_factor=2.5, rope_scaling=V3_ROPE,
+             **routing)
+    assert loader.mismatches(c, prog) == []
+    assert [line for line in loader.mismatches(dict(c, topk_group=3), prog)
+            ] == ["topk_group: file 3, program 2"]
+    leaves["layer2.ffn.router_bias"] = ((8,), "bfloat16")
+    assert loader.mismatches(c, prog) == \
+        ["layer2.ffn.router_bias: file float32, program bfloat16"]
 
 
 def test_coder_file_lists_its_rope_scaling():
@@ -245,6 +324,26 @@ def test_expert_leaves_take_one_experts_fan_in():
     assert W.std_for("layer3.ffn.w_gu", (8, 64, 64)) == 1 / 8
     assert W.std_for("layer3.ffn.router", (64, 8)) == 1 / 8
     assert W.std_for("engram0.tables", (16, 2048, 8)) == 1 / 4
+
+
+def test_v3_sizing_file_is_modelled_at_the_cut_s_sizes():
+    """The DeepSeek-V3 cut whose reference is timed on the chip: every
+    key modelled, and its weights as counted by hand from the
+    published widths (bfloat16)."""
+    c = loader.read("tests/chipbench/data/deepseek-v3-sizing.json", ROOT)
+    assert loader.unmodelled(c) == []
+    assert counters.expert_share(c) == (8, 256)
+    sh = loader.expected_shapes(c)
+
+    def params(prefix):
+        return sum(math.prod(s) for n, s in sh.items()
+                   if n.startswith(prefix))
+    assert params("layer0.mixer.") == 187105280
+    assert params("layer0.ffn.") == 396361728               # dense
+    assert params("layer1.ffn.") == 398196992               # 8 of 256
+    assert sh["layer1.ffn.router_bias"] == (256,)
+    assert sum(params(f"layer{i}.") for i in range(5)) * 2 == 5849352192
+    assert (params("embed.") + params("head.")) * 2 == 3706716160
 
 
 def test_configuration_files_name_only_modelled_keys():
