@@ -15,7 +15,8 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from ..sharding.rules import shard
+from ..kernels.decode_attention.ops import bounded_decode_attention
+from ..sharding.rules import current_ctx, shard
 from .layers import apply_rope, rmsnorm, softcap
 from .params import pd
 
@@ -204,9 +205,17 @@ def layer_view(buf, layer=None):
     return jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
 
 
+def bounded_read(cfg: ModelConfig, kind: str) -> bool:
+    """Whether a decode attention layer of ``kind`` reads each slot's
+    cache only up to its length on the TPU: a global GQA/MHA layer with
+    no logit softcap (``decode_attention``)."""
+    return (cfg.attn_impl != "mla" and kind != "local"
+            and not cfg.attn_logit_softcap)
+
+
 def decode_attention(cfg: ModelConfig, params, h, cache, positions, kind: str,
                      *, layer=None, bf16_scores: bool = False,
-                     window_slice: bool = False):
+                     window_slice: bool = False, lens=None):
     """Single-token decode. h (B,1,d); cache {k,v}: (B,Smax,Hkv,D);
     positions (B,) current index per sequence. Returns (out, new_cache).
 
@@ -216,7 +225,14 @@ def decode_attention(cfg: ModelConfig, params, h, cache, positions, kind: str,
 
     ``window_slice``: sliding-window layers attend to a gathered
     window-sized cache slice instead of masking the full context — cuts
-    the per-step cache read from O(S) to O(window) (§Perf iteration 2)."""
+    the per-step cache read from O(S) to O(window) (§Perf iteration 2).
+
+    ``lens`` (B,): positions each slot attends, 0 for a slot that is not
+    live (default ``positions + 1``). A global layer without a logit
+    softcap on one device lowers on the TPU to the length-bounded kernel
+    (``kernels/decode_attention``), which reads each slot's cache only up
+    to its length; everywhere else, and on every other backend, the layer
+    reads the whole cache and masks it (there ``lens`` is not read)."""
     B = h.shape[0]
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = (h @ params["wq"]).reshape(B, 1, hq, hd)
@@ -256,7 +272,24 @@ def decode_attention(cfg: ModelConfig, params, h, cache, positions, kind: str,
         valid = kpos <= positions[:, None]
         if window > 0:
             valid &= kpos > positions[:, None] - window
-    out = _sdpa(cfg, q, k_att, v_att, valid[:, None, :], bf16_scores)
+
+    def masked(q):
+        return _sdpa(cfg, q, k_att, v_att, valid[:, None, :], bf16_scores)
+
+    ctx = current_ctx()
+    if bounded_read(cfg, kind) and (ctx is None or ctx.mesh.size == 1):
+        if lens is None:
+            lens = positions + 1
+        stack = (new_cache["k"], new_cache["v"]) if layer is not None \
+            else (kc[None], vc[None])
+
+        def bounded(q):
+            return bounded_decode_attention(
+                q[:, 0], *stack, 0 if layer is None else layer,
+                lens)[:, None]
+        out = jax.lax.platform_dependent(q, tpu=bounded, default=masked)
+    else:
+        out = masked(q)
     out = out.reshape(B, 1, hq * hd)
     out = out @ params["wo"]
     return out, new_cache

@@ -116,10 +116,11 @@ def _engram_rows_all_layers(cfg: ModelConfig, flags: RunFlags, params, idx,
 # ---------------------------------------------------------------------------
 
 def forward(cfg: ModelConfig, flags: RunFlags, params, batch, mode: str,
-            positions=None, caches=None, engram_rows=None):
+            positions=None, caches=None, engram_rows=None, kv_lens=None):
     """Shared forward. Returns (h_final, new_caches, aux).
 
-    mode train/prefill: positions (S,) default arange; decode: (B,).
+    mode train/prefill: positions (S,) default arange; decode: (B,), and
+    ``kv_lens`` (B,) the positions each slot's attention reads.
     """
     h = embed_inputs(cfg, params, batch, flags)
     B, S = h.shape[:2]
@@ -145,7 +146,7 @@ def forward(cfg: ModelConfig, flags: RunFlags, params, batch, mode: str,
             h = engram_fuse(cfg, fuse_p, h, rows[si - 1])
         c = caches[si] if caches is not None else None
         h, nc, aux = apply_segment(cfg, flags, seg, params["segments"][si],
-                                   h, positions, c, mode)
+                                   h, positions, c, mode, kv_lens=kv_lens)
         aux_tot += aux
         if new_caches is not None:
             new_caches.append(nc)
@@ -247,12 +248,18 @@ def build_prefill_step(cfg: ModelConfig, flags: RunFlags, max_len: int = 0):
 
 
 def _decode_one(cfg: ModelConfig, flags: RunFlags, params, state, token,
-                rows=None):
+                rows=None, live=None):
     """One decode step: (state, token (B,)) -> (logits (B,V), new_state).
     Shared by the single-token step and the multi-token verify step so the
-    two paths are numerically identical."""
+    two paths are numerically identical.
+
+    ``live`` (B,) bool: slots not live attend nothing (their attention
+    reads no cache and their logits are not meaningful); without it every
+    slot attends its positions up to and including the new one."""
     batch = {"tokens": token[:, None]}
     positions = state["positions"]
+    kv_lens = positions + 1 if live is None \
+        else jnp.where(live, positions + 1, 0)
     eng_layers = cfg.engram_layers()
     if eng_layers and "engram" in params and rows is None:
         idx = decode_engram_indices(cfg.engram, state["last_tokens"],
@@ -260,7 +267,7 @@ def _decode_one(cfg: ModelConfig, flags: RunFlags, params, state, token,
         rows = _engram_rows_all_layers(cfg, flags, params, idx)
     h, new_caches, _ = forward(cfg, flags, params, batch, "decode",
                                positions=positions, caches=state["caches"],
-                               engram_rows=rows)
+                               engram_rows=rows, kv_lens=kv_lens)
     logits = head_logits(_head_params(cfg, params), h[:, 0],
                          cfg.final_logit_softcap, cfg.tie_embeddings)
     new_state = {
@@ -273,22 +280,24 @@ def _decode_one(cfg: ModelConfig, flags: RunFlags, params, state, token,
 
 def build_decode_step(cfg: ModelConfig, flags: RunFlags,
                       external_rows: bool = False):
-    """(params, state, token (B,) [, rows]) -> (logits (B,V), new_state).
+    """(params, state, token (B,) [, rows] [, live]) -> (logits (B,V),
+    new_state).
 
     ``external_rows=True`` takes the Engram rows as an argument — the
     serving engine's prefetch path (retrieval dispatched as its own call
-    before the decode step is enqueued, per the paper's §4.3).
+    before the decode step is enqueued, per the paper's §4.3). ``live``
+    (B,) bool, optional: the slots that are live (``_decode_one``).
 
     Both forms are named ``decode_step``, so a jitted step lowers as
     ``jit_decode_step`` and its device events carry that name."""
     assert not cfg.is_encoder
 
     if external_rows:
-        def decode_step(params, state, token, rows):
-            return _decode_one(cfg, flags, params, state, token, rows)
+        def decode_step(params, state, token, rows, live=None):
+            return _decode_one(cfg, flags, params, state, token, rows, live)
     else:
-        def decode_step(params, state, token):
-            return _decode_one(cfg, flags, params, state, token)
+        def decode_step(params, state, token, live=None):
+            return _decode_one(cfg, flags, params, state, token, live=live)
     return decode_step
 
 

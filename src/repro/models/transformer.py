@@ -140,7 +140,7 @@ def init_block_cache(cfg: ModelConfig, i: int, batch: int, max_len: int,
 
 
 def apply_block(cfg: ModelConfig, flags: RunFlags, sig: tuple, params, h,
-                positions, cache, mode: str, layer=None):
+                positions, cache, mode: str, layer=None, kv_lens=None):
     """One transformer block. mode: train | prefill | decode.
 
     Returns (h, new_cache, aux). ``cache`` is None in train mode (recurrent
@@ -149,7 +149,10 @@ def apply_block(cfg: ModelConfig, flags: RunFlags, sig: tuple, params, h,
     ``layer`` (decode only): ``cache`` is a layer stack and this block is
     layer ``layer`` of it; the whole stack is returned, updated. Attention
     writes only its new rows into it; a recurrent mixer replaces its whole
-    (small) state, which is written back at ``layer``."""
+    (small) state, which is written back at ``layer``.
+
+    ``kv_lens`` (decode only): the positions each slot's attention reads
+    (``decode_attention``'s ``lens``)."""
     t, kind, ffn = sig
     aux = jnp.zeros((), jnp.float32)
     pre = rmsnorm(params["ln1"], h, cfg.norm_eps)
@@ -163,7 +166,7 @@ def apply_block(cfg: ModelConfig, flags: RunFlags, sig: tuple, params, h,
                 out, new_cache = decode_attention(
                     cfg, params["mixer"], pre, cache, positions, kind,
                     layer=layer, bf16_scores=flags.attn_bf16_scores,
-                    window_slice=flags.decode_window_slice)
+                    window_slice=flags.decode_window_slice, lens=kv_lens)
         else:
             if cfg.attn_impl == "mla":
                 out, kv = mla_attention(cfg, params["mixer"], pre, positions,
@@ -241,7 +244,7 @@ def init_segment_cache(cfg: ModelConfig, seg: Segment, batch: int,
 
 
 def apply_segment(cfg: ModelConfig, flags: RunFlags, seg: Segment, params, h,
-                  positions, cache, mode: str):
+                  positions, cache, mode: str, kv_lens=None):
     """Returns (h, new_cache_or_None, aux_sum)."""
     aux_tot = jnp.zeros((), jnp.float32)
     keep_cache = mode != "train"
@@ -250,7 +253,8 @@ def apply_segment(cfg: ModelConfig, flags: RunFlags, seg: Segment, params, h,
         li = seg.layers[j]
         c = cache["prefix"][j] if cache is not None else None
         h, nc, aux = apply_block(cfg, flags, _sig(cfg, li),
-                                 params["prefix"][j], h, positions, c, mode)
+                                 params["prefix"][j], h, positions, c, mode,
+                                 kv_lens=kv_lens)
         aux_tot += aux
         new_prefix.append(nc)
     new_stack = []
@@ -266,7 +270,7 @@ def apply_segment(cfg: ModelConfig, flags: RunFlags, seg: Segment, params, h,
                 c = c_stacked[pos] if c_stacked is not None else None
                 h_, nc, aux = apply_block(cfg, flags, sigs[pos],
                                           p_stacked[pos], h_, positions, c,
-                                          mode)
+                                          mode, kv_lens=kv_lens)
                 aux_ = aux_ + aux
                 ncs.append(nc)
             y = tuple(ncs) if keep_cache else None
@@ -287,7 +291,7 @@ def apply_segment(cfg: ModelConfig, flags: RunFlags, seg: Segment, params, h,
             for pos in range(seg.period):
                 h_, c_all[pos], aux = apply_block(
                     cfg, flags, sigs[pos], p_stacked[pos], h_, positions,
-                    c_all[pos], mode, layer=i)
+                    c_all[pos], mode, layer=i, kv_lens=kv_lens)
                 aux_ = aux_ + aux
             return (h_, aux_, tuple(c_all)), None
 

@@ -102,6 +102,8 @@ from ..core.hashing import (block_engram_indices, block_engram_keys,
                             decode_engram_indices, decode_engram_keys,
                             engram_indices, host_block_keys,
                             pack_segment_keys, prefix_chain_keys)
+from ..kernels.decode_attention.ops import kv_block, rows_read
+from ..models.attention import bounded_read
 from ..models.model import (build_chunk_prefill, build_decode_step,
                             build_prefill_step, init_params)
 from ..models.model import init_decode_state as _init_decode_state
@@ -237,6 +239,10 @@ class EngineStats:
     kv_restore_bytes: int = 0        # KV bytes fetched back on resume
     kv_spill_pages: int = 0          # fixed-size pages spilled
     idle_spills: int = 0             # long-context spills (no preemption)
+    # --- decode attention: cache positions of the decode waves ------------
+    kv_rows_read: int = 0            # read by the length-bounded attention:
+    #   each live slot's length rounded up to the kernel's block, 0 if dead
+    kv_rows_held: int = 0            # held: max_batch x max_len per wave
 
     @property
     def tokens_per_s(self) -> float:
@@ -567,6 +573,18 @@ class Engine:
 
         self.state = self._fresh_state(max_batch)
         self.slots: list[Optional[Request]] = [None] * max_batch
+        # slot liveness on the device, passed to every decode wave: slots
+        # that are not live read no KV. Uploaded again only when the slot
+        # set changes (``_live_for_wave``)
+        self._live = None
+        self._live_slots: Optional[tuple] = None
+        # positions per block of the length-bounded attention, 0 when no
+        # layer of the model reads through it (kv_rows_* stay 0)
+        self._kv_block = kv_block(
+            max_len, cfg.n_kv_heads * cfg.head_dim
+            * jnp.dtype(cfg.dtype).itemsize) if any(
+                t == "attn" and bounded_read(cfg, k)
+                for t, k in zip(cfg.layer_types, cfg.attn_kinds)) else 0
         self.tokens = self._place(jnp.zeros((max_batch,), jnp.int32))
         self.queue: deque[Request] = deque()
         self.done: dict[int, Request] = {}
@@ -1219,12 +1237,34 @@ class Engine:
         """Decode step gated by slot liveness (chunked mode): dead and
         mid-prefill rows keep their positions / recurrent state — the
         prefill jobs' partial KV must not advance under a decode wave."""
-        logits, new_state = self._decode_fn(params, state, tokens)
+        logits, new_state = self._decode_fn(params, state, tokens, live)
         return logits, gate_state(live, new_state, state)
 
     def _decode_ext_gated_fn(self, params, state, tokens, rows, live):
-        logits, new_state = self._decode_ext_fn(params, state, tokens, rows)
+        logits, new_state = self._decode_ext_fn(params, state, tokens, rows,
+                                                live)
         return logits, gate_state(live, new_state, state)
+
+    def _live_for_wave(self, active: list):
+        """The device's (B,) slot-liveness vector for this wave, uploaded
+        again only when the set of live slots changed since the last wave
+        (admission, finish, cancel, preemption, restore). Also counts the
+        cache positions the wave's attention reads and holds."""
+        if tuple(active) != self._live_slots:
+            live = np.zeros((self.max_batch,), np.bool_)
+            live[np.asarray(active)] = True
+            self._live = self._place(jnp.asarray(live))
+            self._live_slots = tuple(active)
+        if self._kv_block:
+            # a live slot attends its prompt and every token decoded so
+            # far, the newest (this wave's input) included
+            lens = np.minimum([len(self.slots[i].prompt)
+                               + len(self.slots[i].out) for i in active],
+                              self.max_len)
+            self.stats.kv_rows_read += int(
+                rows_read(lens, self._kv_block).sum())
+            self.stats.kv_rows_held += self.max_batch * self.max_len
+        return self._live
 
     def _decode_wave(self) -> list:
         """One batched greedy-decode wave over the live slots — exactly one
@@ -1267,24 +1307,22 @@ class Engine:
                                                self.tokens)
                 rows = self.store.gather(
                     self.store.prefetch(len(active), fetch=fetch))
+            live = self._live_for_wave(active)
             if self.prefill_chunk is not None and self._prefill_jobs:
                 # prefill jobs in flight: gate the state update by liveness so
                 # their partial KV / positions are untouched by this wave
-                live = np.zeros((B,), np.bool_)
-                live[np.asarray(active)] = True
-                live_j = jnp.asarray(live)
                 if self._decode_ext is not None:
                     logits, self.state = self._decode_ext_gated(
-                        self.params, self.state, self.tokens, rows, live_j)
+                        self.params, self.state, self.tokens, rows, live)
                 else:
                     logits, self.state = self._decode_gated(
-                        self.params, self.state, self.tokens, live_j)
+                        self.params, self.state, self.tokens, live)
             elif self._decode_ext is not None:
-                logits, self.state = self._decode_ext(self.params, self.state,
-                                                      self.tokens, rows)
+                logits, self.state = self._decode_ext(
+                    self.params, self.state, self.tokens, rows, live)
             else:
                 logits, self.state = self._decode(self.params, self.state,
-                                                  self.tokens)
+                                                  self.tokens, live)
             new_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             self.tokens = new_tok
             if self._pool_mode:

@@ -7,6 +7,7 @@ import pytest
 
 from conftest import reduced
 
+from repro.kernels.decode_attention.ops import kv_block
 from repro.models.model import (build_decode_step, build_prefill_step,
                                 init_decode_state, init_params)
 from repro.models.transformer import RunFlags
@@ -120,6 +121,52 @@ def test_pool_tiers_rank_by_throughput(cfg, params):
     # near-DRAM end-to-end performance
     assert (outs["CXL"].tokens_per_s_emulated
             > 0.95 * outs["DRAM"].tokens_per_s_emulated)
+
+
+def test_decode_waves_upload_liveness_on_change_and_count_kv_rows(
+        cfg, params):
+    """Each decode wave passes the step the device's slot-liveness vector,
+    uploaded again only on a wave whose live slots differ from the last
+    wave's; and the engine counts the cache positions the length-bounded
+    attention reads (each live slot's prompt plus tokens so far, rounded
+    up to the kernel's block; a dead slot none) against those it holds."""
+    B, S = 4, 96
+    eng = Engine(cfg, params=params, max_batch=B, max_len=S,
+                 prompt_bucket=8)
+    block = kv_block(S, cfg.n_kv_heads * cfg.head_dim
+                     * jnp.dtype(cfg.dtype).itemsize)
+    assert block == 32
+    wave = eng._decode_wave
+    seen = []
+
+    def counted():
+        active = [i for i, r in enumerate(eng.slots) if r is not None]
+        lens = [len(eng.slots[i].prompt) + len(eng.slots[i].out)
+                for i in active]
+        before = eng._live
+        events = wave()
+        if active:
+            seen.append((tuple(active), eng._live is not before,
+                         sum(-(-n // block) * block for n in lens)))
+            assert np.asarray(eng._live).tolist() == [
+                i in active for i in range(B)]
+        return events
+
+    eng._decode_wave = counted
+    rng = np.random.RandomState(3)
+    for n, new in ((3, 3), (30, 6), (12, 2)):
+        eng.submit(list(rng.randint(1, cfg.vocab_size, size=n)), max_new=new)
+    rt = eng.runtime()
+    rt.step()
+    rt.step()
+    eng.submit(list(rng.randint(1, cfg.vocab_size, size=5)), max_new=4)
+    rt.drain()
+    changed = [a != b for (a, *_), (b, *_) in zip(seen, [((),)] + seen)]
+    assert [up for _, up, _ in seen] == changed
+    assert 0 < sum(changed) < len(seen)
+    assert any(len(a) < B for a, *_ in seen)
+    assert eng.stats.kv_rows_read == sum(r for *_, r in seen)
+    assert eng.stats.kv_rows_held == len(seen) * B * S
 
 
 def test_update_select_slots_roundtrip(cfg):

@@ -3,7 +3,10 @@ kernels and the one-chip decode step at real widths. The TPU compiler
 refuses what interpret mode accepts (misaligned slices, too much VMEM, a
 program that does not fit HBM), so these guard every change at no chip
 time. A compile that passes is not a chip run."""
+import json
 import os
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +22,9 @@ from repro.models.transformer import RunFlags
 
 # HBM the TPU compiler gives one v5e program (it reports 15.75G of 16 GiB)
 V5E_PROGRAM_HBM = int(15.75 * 2**30)
+ROOT = Path(__file__).resolve().parents[1]
+# the one-chip configurations the benchmark serves
+ONE_CHIP = ["deepseek-7b-1chip", "deepseek-coder-33b-1chip"]
 
 
 @pytest.fixture(scope="module")
@@ -75,30 +81,45 @@ def test_gated_fuse_compiles_at_deepseek_width(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _compiled_decode_step(one_chip, flags):
-    """deepseek-7b-1chip's served decode step as ``Engine`` builds it: 16
-    slots x 1,024 positions, Engram rows passed in, state donated."""
-    cfg = get_config("deepseek-7b-1chip")
+def _served(name):
+    """(cfg, slots, positions) of a one-chip configuration file as the
+    benchmark builds and deploys it."""
+    if str(ROOT) not in sys.path:
+        sys.path.insert(0, str(ROOT))
+    from chipbench import run
+    c = json.loads((ROOT / "chipbench" / "configs" / f"{name}.json")
+                   .read_text())
+    dep = c["deployment"]
+    return run.model_config(c), dep["max_batch"], dep["max_len"]
+
+
+def _compiled_decode_step(one_chip, flags, name):
+    """A one-chip configuration's served decode step as ``Engine`` builds
+    it (deepseek-7b-1chip: 16 slots x 1,024 positions): Engram rows and
+    slot liveness passed in, state donated."""
+    cfg, B, S = _served(name)
     put = lambda tree: jax.tree.map(
         lambda a: _spec(a.shape, a.dtype, one_chip), tree)
     params = put(abstract_params(cfg))
-    state = put(jax.eval_shape(lambda: init_decode_state(cfg, flags, 16,
-                                                         1024)))
-    tokens = _spec((16,), "int32", one_chip)
+    state = put(jax.eval_shape(lambda: init_decode_state(cfg, flags, B, S)))
+    tokens = _spec((B,), "int32", one_chip)
+    live = _spec((B,), "bool", one_chip)
     e = cfg.engram
-    rows = [_spec((16, 1, len(e.orders) * e.emb_dim), cfg.dtype, one_chip)
+    rows = [_spec((B, 1, len(e.orders) * e.emb_dim), cfg.dtype, one_chip)
             for _ in cfg.engram_layers()]
     step = jax.jit(build_decode_step(cfg, flags, external_rows=True),
                    donate_argnums=(1,))
-    return params, step.lower(params, state, tokens, rows).compile()
+    return params, step.lower(params, state, tokens, rows, live).compile()
 
 
-def test_one_chip_decode_step_fits_hbm(one_chip):
-    """deepseek-7b-1chip's served decode step (16 slots x 1,024 positions,
-    state donated) compiles, and with the Engram tables it does not read
-    but keeps resident it fits one v5e."""
+@pytest.mark.parametrize("name", ONE_CHIP)
+def test_one_chip_decode_step_fits_hbm(one_chip, name):
+    """A one-chip configuration's served decode step (state donated)
+    compiles with the length-bounded attention kernel in it, and with the
+    Engram tables it does not read but keeps resident it fits one v5e."""
     params, compiled = _compiled_decode_step(
-        one_chip, RunFlags(attn_bf16_scores=True))
+        one_chip, RunFlags(attn_bf16_scores=True), name)
+    assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     tables = sum(layer["tables"].size * layer["tables"].dtype.itemsize
                  for layer in params["engram"]["layers"])
@@ -108,15 +129,23 @@ def test_one_chip_decode_step_fits_hbm(one_chip):
     assert need < V5E_PROGRAM_HBM, need
 
 
-def test_one_chip_decode_step_writes_kv_rows_in_place(one_chip):
+@pytest.mark.parametrize("name", ONE_CHIP)
+def test_one_chip_decode_step_writes_kv_rows_in_place(one_chip, name):
     """The layer scan of the served decode step (``RunFlags()``, as
     ``Engine`` builds it) writes each slot's new K/V row into the donated,
-    stacked cache and reads its layer there. Its temporaries stay below
-    one layer's K slab (16 x 1,024 x 32 x 128 bf16 = 134 MB): slicing a
-    layer's whole cache out of the stack and writing it back, as the scan
-    once did, needs 404 MB of temporaries and ~60% of the step's modelled
-    cycles."""
-    _, compiled = _compiled_decode_step(one_chip, RunFlags())
-    slab = 16 * 1024 * 32 * 128 * 2
+    stacked cache, and the attention kernel reads its layer there. Its
+    temporaries stay below one layer's K slab (deepseek-7b-1chip: 16 x
+    1,024 x 32 x 128 bf16 = 134 MB; they are ~1.4 MB): slicing a layer's
+    whole cache out of the stack and writing it back, as the scan once
+    did, needs 404 MB of temporaries and ~60% of the step's modelled
+    cycles. deepseek-coder-33b-1chip's step also stages two of its
+    (7,168 x 7,168) attention weights, 206 MB, whatever the attention
+    reads; beside those its temporaries are ~3 MB."""
+    _, compiled = _compiled_decode_step(one_chip, RunFlags(), name)
+    cfg, B, S = _served(name)
+    slab = B * S * cfg.n_kv_heads * cfg.head_dim * 2
+    staged = 0 if name == "deepseek-7b-1chip" else \
+        2 * cfg.d_model * cfg.n_heads * cfg.head_dim * 2
+    assert "tpu_custom_call" in compiled.as_text()
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < slab, temp
+    assert temp < slab + staged, temp
